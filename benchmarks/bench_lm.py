@@ -224,8 +224,12 @@ def run_session(args, trace_out):
         jax.block_until_ready(w_u)
         baseline_wall = time.perf_counter() - t0
 
-        # coalesced guard-ON burst: two handles, one group replay
-        obs_trace.enable()
+        # coalesced guard-ON burst: two handles, one group replay.  CPU
+        # runs price spans against v5e peaks, named explicitly: the ratio
+        # then says how far a CPU run sits from that roofline, not a
+        # device metric
+        from repro.roofline.hw import TPU_V5E
+        obs_trace.enable(obs_trace.Tracer(hw=TPU_V5E))
         k = len(removed) // 2
         t0 = time.perf_counter()
         h1 = sess.delete(removed[:k].tolist())
@@ -362,6 +366,17 @@ def main(argv=None):
         # child process: one variant, JSON on the last stdout line
         print(json.dumps(run_variant(args, args.variant)))
         return
+
+    # the sharded_delta variant runs in a child on forced host devices; a
+    # TPU belongs to the process that first touched it — this one — so the
+    # child would wait on the held chip forever.  Refuse before any work.
+    import jax
+    if jax.default_backend() == "tpu":
+        raise SystemExit(
+            "bench_lm's sharded_delta variant runs in a child process on "
+            "forced host devices and cannot share the TPU this process "
+            "would hold; run the sharded replay on chips with "
+            "`python chip_smoke.py --chips 4`")
 
     from repro.models.registry import count_params
     from repro.configs.registry import get_config

@@ -45,6 +45,7 @@ from repro.core.online import online_deltagrad
 from repro.data.synthetic import binary_classification
 from repro.models.simple import logreg_init, logreg_objective
 from repro.obs import trace as obs_trace
+from repro.roofline.hw import TPU_V5E
 
 # dispatch-bound shape: per-step dispatch dominates gradient FLOPs, which
 # maximises the tracer's relative footprint — the adversarial regime for
@@ -84,7 +85,8 @@ def _run_stream(p, obj, mode):
     if mode == "plain":
         obs_trace.span = _stub_span
     elif mode == "on":
-        obs_trace.enable()
+        # priced against v5e peaks on any device (see bench_lm)
+        obs_trace.enable(obs_trace.Tracer(hw=TPU_V5E))
     try:
         _, ostats = online_deltagrad(obj, hist, ds, reqs, cfg,
                                      mode="delete", warmup=True)

@@ -253,7 +253,9 @@ def main(argv=()) -> None:
     from repro.obs import metrics as obs_metrics
     from repro.obs import trace as obs_trace
     if args.trace_out:
-        obs_trace.enable()
+        from repro.roofline.hw import TPU_V5E
+        # priced against v5e peaks on any device (see bench_lm)
+        obs_trace.enable(obs_trace.Tracer(hw=TPU_V5E))
 
     size = dict(QUICK if args.quick else FULL)
     n_events = args.events or (24 if args.quick else 80)

@@ -107,6 +107,7 @@ from repro.core.store import (EncodedLeaf, HistoryStore, auto_window,
 from repro.data.dataset import Dataset
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
+from repro.roofline.hw import local_hw
 from repro.roofline.replay import scan_segment_cost
 from repro.data.sampler import (ReplaySchedule, addition_mask,
                                 batch_indices, batch_indices_all,
@@ -166,12 +167,15 @@ def _scan_pred(n_params: int, steps: int, r: int, m: int,
                momentum: bool) -> Optional[float]:
     """Roofline-predicted cost (seconds) for a scanned replay segment —
     attached as ``pred_s`` to ``replay.scan`` spans so the exported trace
-    carries measured-vs-roofline ratios.  Returns None (and computes
-    nothing) while tracing is disabled, keeping the tracer-off hot path
-    free of the prediction arithmetic."""
-    if not obs_trace.enabled():
+    carries measured-vs-roofline ratios, priced for the device that runs
+    the segment unless the tracer names a spec.  Returns None (and
+    computes nothing) while tracing is disabled, keeping the tracer-off
+    hot path free of the prediction arithmetic."""
+    tracer = obs_trace.get_tracer()
+    if tracer is None:
         return None
-    return scan_segment_cost(n_params, steps, r, m, momentum=momentum).pred_s
+    return scan_segment_cost(n_params, steps, r, m, momentum=momentum,
+                             hw=tracer.hw or local_hw()).pred_s
 
 
 def _publish_replay_metrics(stats: "RetrainStats", store) -> None:

@@ -196,6 +196,16 @@ class BF16Codec(Codec):
                             stored)
 
 
+def _exact_product_scale(scale: float) -> np.float32:
+    """Round `scale` UP to 17 significant bits.  |q| <= 127 has 7, so every
+    decoded ``q * scale`` is exact in f32 and ``q * scale + base`` rounds
+    once whether or not the compiler contracts it into a fused
+    multiply-add — which XLA decides per fusion, so without this the
+    in-scan and whole-window decodes can differ in the last bit."""
+    m, e = np.frexp(np.float64(scale))
+    return np.float32(np.ldexp(np.ceil(m * 2.0**17) / 2.0**17, e))
+
+
 class Int8Codec(Codec):
     """Symmetric per-leaf absmax int8 quantization."""
 
@@ -207,7 +217,7 @@ class Int8Codec(Codec):
         def enc(x):
             x = np.asarray(x, dtype=np.float32)
             scale = np.max(np.abs(x)) / 127.0 if x.size else 1.0
-            scale = scale if scale > 0 else 1.0
+            scale = _exact_product_scale(scale) if scale > 0 else 1.0
             q = np.clip(np.round(x / scale), -127, 127).astype(np.int8)
             return {"q": q, "scale": np.float32(scale)}
 

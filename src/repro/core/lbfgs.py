@@ -36,6 +36,10 @@ import jax.numpy as jnp
 
 from repro.utils.tree import tree_lincomb, tree_scale, tree_vdot
 
+# the correction is f32 by contract; a TPU's default f32 dot rounds its
+# inputs to bf16, which the curvature pairs' differences do not survive
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 class CompactCoeffs(NamedTuple):
     """Coefficients of the rank-2m correction: Bv = sigma*v - dW^T a - dG^T b."""
@@ -146,10 +150,10 @@ def gram_terms_stacked(dW: jax.Array, dG: jax.Array, v: jax.Array):
     """
     f32 = jnp.float32
     dWf, dGf, vf = dW.astype(f32), dG.astype(f32), v.astype(f32)
-    sw = dWf @ dWf.T
-    sy = dWf @ dGf.T
-    wv = dWf @ vf
-    gv = dGf @ vf
+    sw = jnp.matmul(dWf, dWf.T, precision=_HIGHEST)
+    sy = jnp.matmul(dWf, dGf.T, precision=_HIGHEST)
+    wv = jnp.matmul(dWf, vf, precision=_HIGHEST)
+    gv = jnp.matmul(dGf, vf, precision=_HIGHEST)
     return sw, sy, wv, gv
 
 
@@ -157,7 +161,8 @@ def lbfgs_hvp_stacked(dW: jax.Array, dG: jax.Array, v: jax.Array) -> jax.Array:
     """B v with history stacked as (m, p) rows (oldest first)."""
     sw, sy, wv, gv = gram_terms_stacked(dW, dG, v)
     c = compact_coeffs(sw, sy, wv, gv)
-    return (c.sigma * v - c.a @ dW - c.b @ dG).astype(v.dtype)
+    return (c.sigma * v - jnp.matmul(c.a, dW, precision=_HIGHEST)
+            - jnp.matmul(c.b, dG, precision=_HIGHEST)).astype(v.dtype)
 
 
 # --------------------------------------------------------------------------
@@ -208,7 +213,8 @@ def _pair_gram(a, b):
     axes = tuple(range(1, a.ndim))
     return jax.lax.dot_general(
         a.astype(jnp.float32), b.astype(jnp.float32),
-        ((axes, axes), ((), ())), preferred_element_type=jnp.float32)
+        ((axes, axes), ((), ())), precision=_HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 def _vec_dot(a, x):
@@ -217,7 +223,8 @@ def _vec_dot(a, x):
     axes_x = tuple(range(x.ndim))
     return jax.lax.dot_general(
         a.astype(jnp.float32), x.astype(jnp.float32),
-        ((axes_a, axes_x), ((), ())), preferred_element_type=jnp.float32)
+        ((axes_a, axes_x), ((), ())), precision=_HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 def gram_terms_stacked_pytree(dWs, dGs, v):

@@ -242,7 +242,8 @@ class PlacementPolicy:
     @property
     def mesh(self):
         if self._mesh is None:
-            self._mesh = jax.make_mesh(self.mesh_shape, self.axis_names)
+            from repro.dist.sharding import make_mesh
+            self._mesh = make_mesh(self.mesh_shape, self.axis_names)
         return self._mesh
 
     @property
@@ -657,10 +658,9 @@ class SegmentStreamer(HistoryStore):
         """Read path: fetch mode decodes the whole window to f32 on
         arrival; kernel mode hands the ENCODED window straight to the
         scan (per-step dequant in `entry_at` / the Pallas kernels).
-        Encoded windows decode under jit so XLA contracts the
-        multiply-add exactly like the in-scan slice decode does — that
-        (plus the shared decode expression) is what makes fetch-mode and
-        kernel-mode replays bitwise identical."""
+        Both modes share one decode expression whose product the int8
+        codec keeps exact (`core.history._exact_product_scale`), which is
+        what makes fetch-mode and kernel-mode replays bitwise identical."""
         if self.decode_mode == "kernel":
             return staged
         Wh, Gh = staged
@@ -1108,7 +1108,6 @@ class ShardedReplay:
         (span/sign/momentum/... — everything that changes the program)."""
         if key in self._cache:
             return self._cache[key]
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         specs_w, specs_g = self.store.window_specs
@@ -1120,8 +1119,8 @@ class ShardedReplay:
 
         def call(*args):
             in_specs = lead + (rep,) * (len(args) - len(lead))
-            return shard_map(impl_fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)(*args)
+            return jax.shard_map(impl_fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False)(*args)
 
         jitted = jax.jit(call)
         self._cache[key] = jitted

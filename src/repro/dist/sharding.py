@@ -52,6 +52,20 @@ def make_plan(mesh, cfg=None) -> ShardingPlan:
     return ShardingPlan(mesh=mesh, cfg=cfg)
 
 
+def make_mesh(shape: Tuple[int, ...], axis_names: Tuple[str, ...]):
+    """`jax.make_mesh` with every axis ``Auto``: the shardings this module
+    resolves are placement hints for GSPMD, and the replay's shard_map
+    bodies reduce by hand, so no axis may be ``Explicit`` (the default
+    since JAX 0.8, which rejects e.g. a dot over a sharded contracting
+    dimension instead of inserting the collective)."""
+    import jax
+    from jax.sharding import AxisType
+
+    shape, axis_names = tuple(shape), tuple(axis_names)
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
+
+
 def _fit(plan: ShardingPlan, axis: Optional[str], dim: int) -> Optional[str]:
     """axis if dim divides its mesh size, else replicate."""
     if axis is None:

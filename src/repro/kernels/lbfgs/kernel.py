@@ -30,6 +30,14 @@ from jax.experimental import pallas as pl
 TILE_P = 2048  # f32 lanes: 8 sublanes x 128 lanes x 2 -> 8KB per (8, 2048) tile
 
 
+def _dot(x, y, contract):
+    """f32 contraction inside a kernel; HIGHEST keeps Mosaic from rounding
+    the inputs to bf16 (the correction is f32 by contract)."""
+    return jax.lax.dot_general(x, y, (contract, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
 def _multidot_kernel(dw_ref, dg_ref, v_ref, sw_ref, sy_ref, wv_ref, gv_ref):
     @pl.when(pl.program_id(0) == 0)
     def _init():
@@ -41,14 +49,10 @@ def _multidot_kernel(dw_ref, dg_ref, v_ref, sw_ref, sy_ref, wv_ref, gv_ref):
     dw = dw_ref[...].astype(jnp.float32)  # (m, TILE_P)
     dg = dg_ref[...].astype(jnp.float32)
     v = v_ref[...].astype(jnp.float32)  # (1, TILE_P)
-    sw_ref[...] += jax.lax.dot_general(
-        dw, dw, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    sy_ref[...] += jax.lax.dot_general(
-        dw, dg, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    wv_ref[...] += jax.lax.dot_general(
-        dw, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    gv_ref[...] += jax.lax.dot_general(
-        dg, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    sw_ref[...] += _dot(dw, dw, ((1,), (1,)))
+    sy_ref[...] += _dot(dw, dg, ((1,), (1,)))
+    wv_ref[...] += _dot(dw, v, ((1,), (1,)))
+    gv_ref[...] += _dot(dg, v, ((1,), (1,)))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tile_p"))
@@ -92,10 +96,8 @@ def _rank_update_kernel(dw_ref, dg_ref, v_ref, coef_ref, out_ref):
     b = coefs[1:2, :]
     sigma = coefs[2, 0]
     out = sigma * v
-    out -= jax.lax.dot_general(a, dw, (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-    out -= jax.lax.dot_general(b, dg, (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
+    out -= _dot(a, dw, ((1,), (0,)))
+    out -= _dot(b, dg, ((1,), (0,)))
     out_ref[...] = out.astype(out_ref.dtype)
 
 

@@ -36,6 +36,7 @@ from repro.launch.mesh import make_production_mesh
 from repro.models.registry import active_param_count, build, count_params
 from repro.optim.optimizers import adamw
 from repro.roofline.analysis import roofline_from_compiled
+from repro.roofline.hw import TPU_V5E
 from repro.roofline.model import analytic_cost
 from repro.train.loop import make_train_step
 from repro.train.state import TrainState
@@ -196,6 +197,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, *,
                        n_params=count_params(cfg))
     report = roofline_from_compiled(
         compiled,
+        hw=TPU_V5E,
         arch=arch,
         shape=shape_name,
         mesh_name=mesh_name,

@@ -30,6 +30,7 @@ from repro.dist.sharding import inputs_shardings, make_plan, params_shardings
 from repro.launch.mesh import make_production_mesh
 from repro.models.registry import build, count_params
 from repro.roofline.analysis import roofline_from_compiled
+from repro.roofline.hw import TPU_V5E
 from repro.roofline.model import analytic_cost
 from repro.utils.tree import tree_sub
 
@@ -107,7 +108,8 @@ def lower_deltagrad_cell(arch: str, multi_pod: bool = False,
         3 * R_SEQS * shape.seq_len * cfg.vocab * 4.0 + hvp_bytes
 
     report = roofline_from_compiled(
-        compiled, arch=f"deltagrad-step-{arch}", shape="train_4k",
+        compiled, hw=TPU_V5E, arch=f"deltagrad-step-{arch}",
+        shape="train_4k",
         mesh_name=mesh_name, n_devices=n_dev,
         model_flops=6.0 * n_params * R_SEQS * shape.seq_len,
         analytic_flops=flops, analytic_bytes=bytes_, variant=variant,

@@ -6,31 +6,11 @@ inside functions only (the dry-run sets XLA_FLAGS before any jax import).
 
 from __future__ import annotations
 
-import jax
+from repro.dist.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 (data, model) single pod; 2x16x16 (pod, data, model) multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_debug_mesh(data: int = 2, model: int = 2):
-    """Small forced-host-device mesh for tests."""
-    return jax.make_mesh((data, model), ("data", "model"))
-
-
-def make_replay_mesh(data: int = 0, model: int = 1):
-    """Mesh for sharded DeltaGrad replay (core/store's mesh-parallel path):
-    batch-sharded per-example gradients over ``data``, optional ``model``
-    axis for the history-leaf placements.  ``data=0`` → all local devices.
-
-    Most callers want `repro.core.store.PlacementPolicy` (a picklable
-    descriptor that builds this mesh lazily); this helper is for code that
-    already holds devices."""
-    if not data:
-        data = jax.local_device_count() // max(1, model)
-    if model > 1:
-        return jax.make_mesh((data, model), ("data", "model"))
-    return jax.make_mesh((data,), ("data",))
+    return make_mesh(shape, axes)

@@ -50,13 +50,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import get_config
+from repro.launch.cache import enable_compile_cache
 from repro.models.registry import build
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
 
-def unlearn_main(argv) -> None:
-    """Stand up the online unlearning service and drive a request stream."""
+def unlearn_main(argv) -> dict:
+    """Stand up the online unlearning service and drive a request stream;
+    returns the results dict that ``--bench-out`` writes.  Raises if any
+    request fails or is not served within its wait."""
     import json
 
     from repro.core.deltagrad import DeltaGradConfig
@@ -118,7 +121,8 @@ def unlearn_main(argv) -> None:
                     help="enable the span tracer and write a Chrome/"
                          "Perfetto trace-event JSON here ('' disables); "
                          "the metrics registry lands beside it as "
-                         "<path>.metrics.jsonl")
+                         "<path>.metrics.jsonl; needs a device with peak "
+                         "rates in repro.roofline.hw")
     ap.add_argument("--profile-dir", default="",
                     help="capture a jax.profiler device trace into this "
                          "directory ('' disables) — opt-in, for XLA-level "
@@ -126,7 +130,10 @@ def unlearn_main(argv) -> None:
     args = ap.parse_args(argv)
 
     if args.trace_out:
-        obs_trace.enable()
+        # replay spans are priced for this device; one with no peak rates
+        # in roofline/hw.py is refused here, before any work is done
+        from repro.roofline.hw import local_hw
+        obs_trace.enable(obs_trace.Tracer(hw=local_hw()))
     if args.profile_dir:
         jax.profiler.start_trace(args.profile_dir)
 
@@ -359,7 +366,9 @@ def unlearn_main(argv) -> None:
         sched.start()
         res = LoadGenerator(sched).open_loop(events)
         for tk in res.tickets:
-            tk.wait(timeout=60.0)
+            if not tk.wait(timeout=60.0):  # wait() raises a failed request
+                raise RuntimeError(
+                    f"request {tk.req.seq} was not served within 60 s")
         # lone tail, then silence: only the executor's deadline tick fires
         used = {r for ev in events if ev.rows for r in ev.rows}
         live = np.flatnonzero(sess_f.algorithm.live[:args.n])
@@ -406,6 +415,7 @@ def unlearn_main(argv) -> None:
                      if e["name"] == "replay.scan")
         print(f"wrote {args.trace_out} ({len(tracer.events())} spans, "
               f"{n_scan} replay.scan) + {args.trace_out}.metrics.jsonl")
+    return results
 
 
 def decode_main() -> None:
@@ -469,6 +479,7 @@ def decode_main() -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     if len(sys.argv) > 1 and sys.argv[1] == "unlearn":
         unlearn_main(sys.argv[2:])
     else:

@@ -25,6 +25,7 @@ import numpy as np
 from repro.configs.registry import get_config
 from repro.data.sampler import batch_indices
 from repro.data.synthetic import binary_classification, token_stream
+from repro.launch.cache import enable_compile_cache
 from repro.models.registry import build
 from repro.optim.optimizers import adamw
 from repro.optim.schedules import warmup_cosine
@@ -114,6 +115,7 @@ def train_paper(args) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")

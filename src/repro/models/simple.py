@@ -12,6 +12,13 @@ import numpy as np
 from repro.core.deltagrad import Objective
 
 
+def _mm(x, w):
+    """The models' matmul.  They are f32 by contract (histories, replays
+    and the exact-retrain reference); HIGHEST because a TPU's default f32
+    matmul rounds its inputs to bf16."""
+    return jnp.matmul(x, w, precision=jax.lax.Precision.HIGHEST)
+
+
 # --------------------------------------------------------------------------
 # Binary logistic regression (RCV1 / HIGGS experiments)
 # --------------------------------------------------------------------------
@@ -26,7 +33,7 @@ def logreg_init(d: int, seed: int = 0):
 
 
 def logreg_per_example_loss(params, batch: Dict[str, jax.Array]) -> jax.Array:
-    logits = batch["x"] @ params["w"] + params["b"]
+    logits = _mm(batch["x"], params["w"]) + params["b"]
     y = batch["y"].astype(jnp.float32)
     # numerically stable BCE-with-logits
     return jnp.maximum(logits, 0.0) - logits * y + jnp.log1p(jnp.exp(-jnp.abs(logits)))
@@ -61,7 +68,7 @@ def multiclass_init(d: int, num_classes: int, seed: int = 0):
 
 
 def multiclass_per_example_loss(params, batch):
-    logits = batch["x"] @ params["w"] + params["b"]
+    logits = _mm(batch["x"], params["w"]) + params["b"]
     logz = jax.nn.logsumexp(logits, axis=-1)
     true = jnp.take_along_axis(logits, batch["y"][:, None].astype(jnp.int32), axis=-1)[
         :, 0
@@ -97,8 +104,8 @@ def mlp_init(d: int, hidden: int, num_classes: int, seed: int = 0):
 
 
 def mlp_per_example_loss(params, batch):
-    h = jax.nn.relu(batch["x"] @ params["w1"] + params["b1"])
-    logits = h @ params["w2"] + params["b2"]
+    h = jax.nn.relu(_mm(batch["x"], params["w1"]) + params["b1"])
+    logits = _mm(h, params["w2"]) + params["b2"]
     logz = jax.nn.logsumexp(logits, axis=-1)
     true = jnp.take_along_axis(logits, batch["y"][:, None].astype(jnp.int32), axis=-1)[
         :, 0

@@ -21,7 +21,9 @@ Roofline hook: a span opened with a ``pred_s=<seconds>`` attribute (see
 `repro.roofline.replay`) closes with ``measured_s`` and
 ``roofline_ratio`` (measured / predicted) computed into its args, so
 every replay span in the exported trace carries predicted-vs-measured
-cost.
+cost.  Predictions use the tracer's ``hw`` spec when one is given, else
+the peaks of the device that runs the span (`repro.roofline.hw.local_hw`,
+which raises for a device it has no peaks for).
 
 See `repro.obs` for the span/metric naming contract.
 """
@@ -146,8 +148,9 @@ class Tracer:
     and all completed spans serialize into one buffer under a lock."""
 
     def __init__(self, clock: Callable[[], float] = time.perf_counter,
-                 max_events: int = 1_000_000):
+                 max_events: int = 1_000_000, hw: Optional[Any] = None):
         self.clock = clock
+        self.hw = hw  # roofline.hw.HwSpec that spans are priced against
         self.max_events = int(max_events)
         self.dropped = 0
         self._lock = threading.Lock()

@@ -20,7 +20,7 @@ import re
 from dataclasses import asdict, dataclass, field
 from typing import Dict, List, Optional
 
-from repro.roofline.hw import HwSpec, TPU_V5E
+from repro.roofline.hw import HwSpec
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1, "f8e4m3": 1,
@@ -292,8 +292,8 @@ def roofline_from_compiled(
     shape: str,
     mesh_name: str,
     n_devices: int,
+    hw: HwSpec,
     model_flops: float = 0.0,
-    hw: HwSpec = TPU_V5E,
     hlo_text: Optional[str] = None,
     note: str = "",
     variant: str = "baseline",

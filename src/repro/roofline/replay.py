@@ -29,7 +29,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-from repro.roofline.hw import TPU_V5E, HwSpec
+from repro.roofline.hw import HwSpec
 
 __all__ = ["ReplayCost", "replay_step_cost", "scan_segment_cost"]
 
@@ -52,9 +52,9 @@ class ReplayCost:
         return "compute" if self.t_compute >= self.t_memory else "memory"
 
 
-def replay_step_cost(n_params: int, r_changed: int, m_history: int,
-                     momentum: bool = False, dtype_bytes: int = 4,
-                     hw: HwSpec = TPU_V5E) -> ReplayCost:
+def replay_step_cost(n_params: int, r_changed: int, m_history: int, *,
+                     hw: HwSpec, momentum: bool = False,
+                     dtype_bytes: int = 4) -> ReplayCost:
     """Cost of ONE corrected replay step (see the module docstring)."""
     P = float(max(1, n_params))
     r = float(max(1, r_changed))
@@ -72,9 +72,8 @@ def replay_step_cost(n_params: int, r_changed: int, m_history: int,
 
 
 def scan_segment_cost(n_params: int, steps: int, r_changed: int,
-                      m_history: int, momentum: bool = False,
-                      dtype_bytes: int = 4,
-                      hw: HwSpec = TPU_V5E) -> ReplayCost:
+                      m_history: int, *, hw: HwSpec, momentum: bool = False,
+                      dtype_bytes: int = 4) -> ReplayCost:
     """Cost of a scanned segment of ``steps`` corrected replay steps."""
     one = replay_step_cost(n_params, r_changed, m_history,
                            momentum=momentum, dtype_bytes=dtype_bytes,

@@ -20,6 +20,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from repro.dist.sharding import make_mesh
+
 
 @dataclass
 class ElasticDecision:
@@ -63,7 +65,7 @@ def plan_remesh(
 
 def build_mesh(decision: ElasticDecision) -> Mesh:
     assert decision.ok, decision.reason
-    return jax.make_mesh(decision.mesh_shape, decision.axis_names)
+    return make_mesh(decision.mesh_shape, decision.axis_names)
 
 
 def reshard_state(state, new_shardings):

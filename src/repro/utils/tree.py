@@ -33,11 +33,13 @@ def tree_axpy(s, x, y):
 
 
 def tree_vdot(a, b):
-    """Full-precision inner product <a, b> over every leaf."""
+    """Full-precision inner product <a, b> over every leaf (HIGHEST: a
+    TPU's default f32 dot rounds its inputs to bf16)."""
     leaves_a = jax.tree.leaves(a)
     leaves_b = jax.tree.leaves(b)
     parts = [
-        jnp.vdot(x.astype(jnp.float32), y.astype(jnp.float32))
+        jnp.vdot(x.astype(jnp.float32), y.astype(jnp.float32),
+                 precision=jax.lax.Precision.HIGHEST)
         for x, y in zip(leaves_a, leaves_b)
     ]
     return jnp.sum(jnp.stack(parts))

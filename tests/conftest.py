@@ -8,20 +8,3 @@ import pytest
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)
-
-
-@pytest.fixture(scope="module", autouse=True)
-def _bound_compile_cache():
-    """Clear jax's global jit caches at module boundaries.
-
-    The suite compiles hundreds of distinct programs (pow2-bucketed
-    serving shapes, streamed/sharded scan variants, ...); jaxlib 0.4.36's
-    CPU backend segfaults inside `backend_compile` once enough compiled
-    executables accumulate in one process (reproducible at suite scale,
-    never in any module alone).  Clearing per module keeps within-module
-    caching — cross-module cache hits were never load-bearing, since
-    engines jit per instance."""
-    import jax
-
-    jax.clear_caches()
-    yield

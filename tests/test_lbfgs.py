@@ -116,3 +116,52 @@ def test_hvp_linear_in_v(m, p, seed):
     scale = float(jnp.max(jnp.abs(rhs))) + 1.0
     np.testing.assert_allclose(np.asarray(lhs) / scale,
                                np.asarray(rhs) / scale, atol=5e-4)
+
+
+def _f32_contractions():
+    """(name, fn, args) for every f32 contraction on the replay path."""
+    from functools import partial
+
+    from repro.kernels.lbfgs.ops import lbfgs_hvp_fused
+    from repro.kernels.lbfgs.ref import multidot_ref, rank_update_ref
+    from repro.models.simple import (logreg_init, logreg_per_example_loss,
+                                     mlp_init, mlp_per_example_loss)
+    from repro.utils.tree import tree_vdot
+
+    dW, dG, v, _ = make_history(2, 300)
+    c = jnp.ones((2,))
+    x = jnp.ones((4, 6))
+    y = jnp.zeros((4,), jnp.int32)
+
+    def grad_of(loss):
+        return jax.grad(lambda p: loss(p, {"x": x, "y": y}).mean())
+
+    return {
+        "mlp_grad": (grad_of(mlp_per_example_loss), (mlp_init(6, 5, 3),)),
+        "logreg_grad": (grad_of(logreg_per_example_loss), (logreg_init(6),)),
+        "tree_vdot": (tree_vdot, ({"a": dW}, {"a": dG})),
+        "hvp_stacked": (lbfgs_hvp_stacked, (dW, dG, v)),
+        "hvp_stacked_pytree": (lbfgs_hvp_stacked_pytree,
+                               ({"a": dW}, {"a": dG}, {"a": v})),
+        "hvp_fused_kernels": (partial(lbfgs_hvp_fused, interpret=True),
+                              (dW, dG, v)),
+        "multidot_ref": (multidot_ref, (dW, dG, v)),
+        "rank_update_ref": (rank_update_ref, (dW, dG, v, c, c, c[0])),
+    }
+
+
+@pytest.mark.parametrize("name", ["mlp_grad", "logreg_grad", "tree_vdot",
+                                  "hvp_stacked", "hvp_stacked_pytree",
+                                  "hvp_fused_kernels", "multidot_ref",
+                                  "rank_update_ref"])
+def test_f32_contractions_run_at_highest(name):
+    """Histories, replays and the exact-retrain reference are f32 by
+    contract.  A TPU's default f32 dot rounds its inputs to bf16, so every
+    contraction on that path (forward and backward, in XLA and inside the
+    Pallas kernels) must ask for HIGHEST itself."""
+    fn, args = _f32_contractions()[name]
+    jaxpr = str(jax.make_jaxpr(fn)(*args))
+    n_dots = jaxpr.count("dot_general[")
+    assert n_dots > 0
+    assert jaxpr.count(
+        "precision=(Precision.HIGHEST, Precision.HIGHEST)") == n_dots

@@ -330,7 +330,10 @@ class TestReplayInstrumentation:
         cfg = dataclasses.replace(
             DeltaGradConfig(period=5, burn_in=5, history_size=2),
             impl="scan")
-        tr = obs_trace.enable(Tracer())
+        from repro.roofline.hw import TPU_V5E
+
+        # the CPU has no peak rates: price against the v5e spec explicitly
+        tr = obs_trace.enable(Tracer(hw=TPU_V5E))
         try:
             online_deltagrad(obj, hist, ds, [3, 11], cfg, mode="delete")
         finally:
@@ -345,6 +348,18 @@ class TestReplayInstrumentation:
                 args["measured_s"] / args["pred_s"])
         # the commit span closes out every online replay
         assert any(e["name"] == "replay.commit" for e in tr.events())
+
+    def test_serve_trace_out_refuses_a_device_without_peaks(self, tmp_path):
+        """`serve unlearn --trace-out` prices replay spans for the device
+        that runs them; on one with no peak rates (the CPU) it stops at
+        startup, before any session is built, with no tracer left on."""
+        from repro.launch.serve import unlearn_main
+
+        with pytest.raises(KeyError, match="no peak rates"):
+            unlearn_main(["--trace-out", str(tmp_path / "t.json"),
+                          "--bench-out", ""])
+        assert not obs_trace.enabled()
+        assert not (tmp_path / "t.json").exists()
 
     def test_committed_obs_baseline_passes_against_itself(self):
         """`check_bench --suite obs` must accept its own committed

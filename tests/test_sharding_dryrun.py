@@ -89,10 +89,11 @@ SUBPROCESS_PROG = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P
     from repro.configs.registry import get_config
     from repro.configs.base import ShapeConfig
-    from repro.dist.sharding import make_plan, params_shardings, inputs_shardings
+    from repro.dist.sharding import (inputs_shardings, make_mesh, make_plan,
+                                     params_shardings)
     from repro.models.registry import build
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = get_config("internlm2-1.8b").reduced(d_model=64, n_heads=4,
                                                n_kv_heads=2, d_ff=128,
                                                vocab=256, d_head=16)
