@@ -850,8 +850,9 @@ def run_replay(
                     # compilations exist per stream — the prefix re-run is
                     # the real cost when fallbacks are dense (ROADMAP: a
                     # lax.while_loop formulation could keep this on device).
-                    fell = np.flatnonzero(
-                        (plan[t:b] != SKIP) & ~np.asarray(oks))
+                    with obs_trace.span("replay.host_sync", kind="guard"):
+                        ok = np.asarray(oks)
+                    fell = np.flatnonzero((plan[t:b] != SKIP) & ~ok)
                     if fell.size:
                         tf = t + int(fell[0])
                         with obs_trace.span("replay.guard_retry", t=tf,
@@ -944,7 +945,8 @@ def _host_explicit_step(grad_fn, buffer, params, vel, t, store, cols, sd,
     params, vel, dw, dg, admit = _explicit_step(
         params, vel, t, w_t, g_t, cols, sd, Bf, mom, grad_fn=grad_fn,
         sign=sign, momentum=momentum)
-    curv, ss = np.asarray(admit)
+    with obs_trace.span("replay.host_sync", kind="admit"):
+        curv, ss = np.asarray(admit)
     if not buffer.add_pair(dw, dg, float(curv), float(ss)):
         stats.pairs_rejected += 1
     stats.grad_examples += int(k + dB)
@@ -1414,8 +1416,9 @@ def run_online_request(
                     # rewrites the exact post-request gradient; the failed
                     # segment's outputs are never noted, so they are simply
                     # dropped from the flush.
-                    fell = np.flatnonzero(
-                        (plan[t:b] != SKIP) & ~np.asarray(oks))
+                    with obs_trace.span("replay.host_sync", kind="guard"):
+                        ok = np.asarray(oks)
+                    fell = np.flatnonzero((plan[t:b] != SKIP) & ~ok)
                     if fell.size:
                         tf = t + int(fell[0])
                         with obs_trace.span("replay.guard_retry", t=tf,

@@ -291,7 +291,8 @@ class OnlineEngine:
                     f"{self.ds.n}, got {row}) — an original row would be "
                     "double-counted")
                 assert row not in self.added, f"row {row} already added"
-        sched = self._schedule(op, rows)
+        with obs_trace.span("online.schedule_build", op=op, k=len(rows)):
+            sched = self._schedule(op, rows)
 
         # whole-replay roofline lower bound (None — and not computed —
         # while tracing is off); the tracer stamps the measured wall and

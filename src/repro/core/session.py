@@ -119,6 +119,7 @@ from repro.core.online import OnlineEngine, OnlineStats
 from repro.core.privacy import PrivacyConfig
 from repro.core.store import PlacementPolicy
 from repro.data.dataset import Dataset
+from repro.obs import trace as obs_trace
 from repro.train import checkpoint as ckpt
 
 
@@ -709,9 +710,10 @@ class UnlearnerSession:
         # size the add-column block for the whole plan once so the padded
         # schedule width (and every compiled shape) stays put across it
         n_adds = sum(len(q.rows) for _, q in pending if q.op == "add")
-        algo.begin_plan(n_adds)
+        with obs_trace.span("session.plan", requests=len(pending)):
+            algo.begin_plan(n_adds)
+            groups = plan_requests(pending)
         out: List[UnlearnResponse] = []
-        groups = plan_requests(pending)
         for gi, group in enumerate(groups):
             op = group[0][1].op
             rows = [r for _, q in group for r in q.rows]

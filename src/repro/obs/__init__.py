@@ -7,11 +7,17 @@ percentile helper, and untyped `RetrainStats.extra` dicts as the only
 window into engine/store behavior):
 
   * `obs.trace`   — thread-safe monotonic span tracer with Chrome/Perfetto
-                    trace-event export; near-zero cost while disabled.
+                    trace-event export, and a sink into the JAX profiler's
+                    trace; near-zero cost while both are off.
   * `obs.metrics` — counters / gauges / fixed-bucket histograms in one
                     registry, with JSONL and Prometheus-text exporters.
 
-Enable tracing with ``repro.obs.trace.enable()`` (the serve CLI's
+Spans reach two sinks.  While a JAX profiler trace runs
+(``jax.profiler.start_trace``; the on-chip benchmark's ``--trace 1``),
+every span is also a ``TraceAnnotation`` on the ``.xplane.pb`` host plane,
+on the device trace's clock, under its own name with its int and str args
+as event stats; no tracer is needed.  Install a `Tracer` with
+``repro.obs.trace.enable()`` for the Chrome export (the serve CLI's
 ``--trace-out`` flag and ``benchmarks/bench_serve.py --trace-out`` do this
 and export the trace); metrics publish unconditionally — read them with
 ``repro.obs.metrics.get_registry().snapshot()`` or either exporter.
@@ -24,21 +30,44 @@ SPAN CONTRACT — every span name, where it is emitted, and its args:
     replay.scan             core.engine         t0, t1, pred_s, measured_s,
                                                 roofline_ratio
     replay.explicit         core.engine         t0, steps
+    replay.host_sync        core.engine         kind: "admit" (an explicit
+                                                step's admission scalars)
+                                                or "guard" (a segment's
+                                                guard flags) read back
     replay.guard_retry      core.engine         t, prefix
     replay.commit           core.engine         regions
     online.warmup           core.online         ops
+    online.schedule_build   core.online         op, k  (host-side schedule
+                                                of a request group)
     online.request          core.online         op, k, pred_s, measured_s,
                                                 roofline_ratio
+    session.plan            core.session        requests  (begin_plan and
+                                                plan_requests of a flush)
     store.window_stage      core.store          wid  (staging-pool thread)
     store.prefetch_wait     core.store          wid
     store.window            core.store          wid, hit
     serve.admit             serve.scheduler     op, tenant, cls
-    serve.batch             serve.executor      size, op
+    serve.batch             serve.executor      batch, size, op  (batch is
+                                                `QueuedRequest.batch_id` of
+                                                the batch's requests)
+    serve.submit            serve.executor      requests  (session.submit
+                                                of each request of a batch)
+    serve.publish           serve.executor      (block_until_ready on the
+                                                published parameters)
+    serve.hold              serve.executor      (the executor waiting while
+                                                the flush policy holds)
+
+    The spans of one served request share its batch id: they nest, on the
+    executor thread, inside the ``serve.batch`` span that carries it.
 
     ``pred_s`` is the roofline-predicted span cost attached by
     `repro.roofline.replay`; the tracer stamps ``measured_s`` and
     ``roofline_ratio`` (measured / predicted) on span exit, so every
-    replay span in a trace carries predicted-vs-measured cost.
+    replay span in a `Tracer`'s Chrome export carries predicted-vs-
+    measured cost.  The hook serves that export and the CPU benchmark's
+    gates only: ``pred_s`` is computed only while a `Tracer` is installed
+    (`enabled()`), never for a profiler-only span, and it is a float, so
+    it never reaches the profiler's event.
 
 METRIC CONTRACT — every metric name, its type/unit, and the owner that
 publishes it:

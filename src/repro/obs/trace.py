@@ -1,14 +1,27 @@
-"""Thread-safe span tracer with Chrome/Perfetto trace-event export.
+"""Thread-safe span tracer with Chrome/Perfetto trace-event export, and a
+sink into the JAX profiler's trace.
 
 One module-level tracer serves the whole process: instrumentation sites
-call ``span("replay.scan", rows=K)`` unconditionally, and the call is a
-near-zero-cost no-op until someone calls `enable()` (a module attribute
-load, a None check, and one small dict — no locks, no clock reads).  When
-enabled, every span records wall time from a MONOTONIC clock
-(`time.perf_counter` by default; inject a virtual clock for deterministic
-tests), the recording thread (executor worker, streamer staging pool,
-main), and its same-thread parent span, then lands in one shared event
-buffer under a lock.
+call ``span("replay.scan", rows=K)`` unconditionally.  Each span goes to
+up to two sinks:
+
+  * the JAX profiler: while a profiler trace runs
+    (``jax.profiler.start_trace``), every span also enters a
+    ``jax.profiler.TraceAnnotation`` under its own name, with its int and
+    str args as event stats (never anything else: an arg is never
+    formatted, so no device array is ever read back).  The span then lands
+    on the ``.xplane.pb`` host plane, on the same clock as the device's
+    operations, so a device-idle gap can be named by the program span
+    open across it.  This needs no `Tracer`.
+  * the installed `Tracer`, after `enable()`.
+
+With neither, the call is a near-zero-cost no-op (a module attribute
+load, a None check, the profiler's own is-enabled check, and one small
+dict — no locks, no clock reads).  When a tracer is enabled, every span
+records wall time from a MONOTONIC clock (`time.perf_counter` by default;
+inject a virtual clock for deterministic tests), the recording thread
+(executor worker, streamer staging pool, main), and its same-thread parent
+span, then lands in one shared event buffer under a lock.
 
 Export is the Chrome trace-event JSON format (``"X"`` complete events +
 thread-name metadata), so a serve run's trace opens directly in
@@ -17,13 +30,16 @@ timestamp containment, and cross-thread work (a scan on the executor
 thread overlapping a window stage on the prefetch pool) shows as parallel
 tracks.
 
-Roofline hook: a span opened with a ``pred_s=<seconds>`` attribute (see
+Roofline hook (the `Tracer`'s Chrome export and the CPU benchmark's gates
+only): a span opened with a ``pred_s=<seconds>`` attribute (see
 `repro.roofline.replay`) closes with ``measured_s`` and
 ``roofline_ratio`` (measured / predicted) computed into its args, so
 every replay span in the exported trace carries predicted-vs-measured
 cost.  Predictions use the tracer's ``hw`` spec when one is given, else
 the peaks of the device that runs the span (`repro.roofline.hw.local_hw`,
-which raises for a device it has no peaks for).
+which raises for a device it has no peaks for).  Sites compute ``pred_s``
+only while `enabled()` (a `Tracer` is installed), so a profiler-only span
+never computes it.
 
 See `repro.obs` for the span/metric naming contract.
 """
@@ -35,15 +51,20 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Tracer", "Span", "NOOP_SPAN", "span", "enable", "disable",
            "enabled", "get_tracer"]
 
 _active: Optional["Tracer"] = None
+# True while a JAX profiler trace runs (bound once: a cheap native check)
+_profiling = TraceAnnotation.is_enabled
 
 
 def enabled() -> bool:
-    """True when a tracer is installed (use to gate attr computation that
-    would otherwise run on the disabled hot path)."""
+    """True when a `Tracer` is installed — not merely while the JAX
+    profiler traces.  Gate attr computation on it that would otherwise run
+    on the hot path (the roofline ``pred_s``)."""
     return _active is not None
 
 
@@ -85,32 +106,64 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
+def _annotation(name: str, attrs: Dict[str, Any]) -> TraceAnnotation:
+    """The profiler event of a span: its int and str args only."""
+    return TraceAnnotation(name, **{k: v for k, v in attrs.items()
+                                    if isinstance(v, (int, str))})
+
+
+class _ProfilerSpan:
+    """A span while the JAX profiler traces and no `Tracer` is installed:
+    the profiler's event alone."""
+
+    __slots__ = ("ann",)
+
+    def __init__(self, name: str, attrs: Dict[str, Any]):
+        self.ann = _annotation(name, attrs)
+
+    def __enter__(self) -> "_ProfilerSpan":
+        self.ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.ann.__exit__(*exc)
+        return False
+
+    def set(self, **attrs) -> "_ProfilerSpan":
+        return self  # a profiler event's stats are fixed at its start
+
+
 def span(name: str, **attrs):
     """``with span("replay.scan", rows=K): ...`` — the one instrumentation
-    entry point.  Disabled: returns the shared no-op span immediately."""
+    entry point.  With no tracer installed it returns a profiler-only span
+    while the JAX profiler traces, else the shared no-op span."""
     t = _active
     if t is None:
-        return NOOP_SPAN
+        return _ProfilerSpan(name, attrs) if _profiling() else NOOP_SPAN
     return Span(t, name, attrs)
 
 
 class Span:
     """One live span (context manager).  `set(**attrs)` adds args mid-span
-    (e.g. a result size known only after the work ran)."""
+    (e.g. a result size known only after the work ran; the tracer's record
+    only, not the profiler's event)."""
 
-    __slots__ = ("tracer", "name", "args", "t0")
+    __slots__ = ("tracer", "name", "args", "t0", "ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self.tracer = tracer
         self.name = name
         self.args = args
         self.t0 = 0.0
+        self.ann = _annotation(name, args) if _profiling() else None
 
     def set(self, **attrs) -> "Span":
         self.args.update(attrs)
         return self
 
     def __enter__(self) -> "Span":
+        if self.ann is not None:
+            self.ann.__enter__()
         self.tracer._stack().append(self.name)
         self.t0 = self.tracer.clock()
         return self
@@ -130,6 +183,8 @@ class Span:
         if stack:
             args.setdefault("parent", stack[-1])
         tr._record(self.name, self.t0, t1, args)
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
         return False
 
 

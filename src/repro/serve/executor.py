@@ -64,7 +64,8 @@ class Executor:
                 # sleep exactly until the earliest ready time, but stay
                 # interruptible so stop() never hangs on a held batch
                 wait = sched.wait_hint if sched.wait_hint else tick
-                self._stop.wait(min(wait, tick))
+                with obs_trace.span("serve.hold"):
+                    self._stop.wait(min(wait, tick))
                 continue
             self.serve_batch(batch)
 
@@ -75,28 +76,32 @@ class Executor:
 
         sched = self.scheduler
         session = sched.session
+        # the lock is held before the id is read: `_batch_ids` moves only
+        # in `note_service`, under it
         with self._serve_lock, obs_trace.span(
-                "serve.batch", size=len(batch),
+                "serve.batch", batch=sched._batch_ids + 1, size=len(batch),
                 op=batch[0].op if batch else ""):
             try:
                 cap_before = sched._row_cap_now()
                 t_disp = sched.clock()
                 handles = []
-                for q in batch:
-                    try:
-                        h = session.submit(op=q.op, rows=q.rows,
-                                           data=q.data, coalesce=q.coalesce)
-                        # adds resolve their appended row ids at submit
-                        # time; reflect them so the trace log / parity
-                        # replays see the served rows
-                        q.rows = list(h.request.rows)
-                        handles.append((q, h))
-                    except Exception as e:  # noqa: BLE001 — per-req fault
-                        q.error = e
-                        q.t_dispatch = t_disp
-                        q.t_done = sched.clock()
-                        q.batch_id = sched._batch_ids + 1
-                        q.done.set()
+                with obs_trace.span("serve.submit", requests=len(batch)):
+                    for q in batch:
+                        try:
+                            h = session.submit(op=q.op, rows=q.rows,
+                                               data=q.data,
+                                               coalesce=q.coalesce)
+                            # adds resolve their appended row ids at
+                            # submit time; reflect them so the trace log /
+                            # parity replays see the served rows
+                            q.rows = list(h.request.rows)
+                            handles.append((q, h))
+                        except Exception as e:  # noqa: BLE001 — per-req
+                            q.error = e
+                            q.t_dispatch = t_disp
+                            q.t_done = sched.clock()
+                            q.batch_id = sched._batch_ids + 1
+                            q.done.set()
                 # one flush per batch: the planner coalesces the run into
                 # one group replay.  flush() isolates a failing group by
                 # requeueing the groups behind it, so keep flushing until
@@ -111,7 +116,8 @@ class Executor:
                         break
                 if handles:
                     try:
-                        jax.block_until_ready(session._algorithm.params)
+                        with obs_trace.span("serve.publish"):
+                            jax.block_until_ready(session._algorithm.params)
                     except Exception:  # noqa: BLE001 — per-handle below
                         pass
                 t_done = sched.clock()

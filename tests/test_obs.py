@@ -14,7 +14,10 @@ The observability layer's contract (see `repro.obs`'s docstring tables):
     percentiles from it — the repo's ONE quantile code path;
   * JSONL and Prometheus exports round-trip the registry;
   * a real scan replay under a live tracer emits roofline-annotated
-    ``replay.scan`` spans (the BENCH_obs acceptance invariant).
+    ``replay.scan`` spans (the BENCH_obs acceptance invariant);
+  * while the JAX profiler traces, spans land on the ``.xplane.pb`` host
+    plane under their own names, with or without a tracer, carrying only
+    their int and str args (never ``pred_s``).
 """
 
 import json
@@ -23,6 +26,7 @@ import subprocess
 import sys
 import threading
 import time
+import warnings
 
 import numpy as np
 import pytest
@@ -375,3 +379,177 @@ class TestReplayInstrumentation:
             env={k: v for k, v in os.environ.items()
                  if k != "GITHUB_STEP_SUMMARY"}, cwd=REPO)
         assert proc.returncode == 0, proc.stdout + proc.stderr
+
+
+# ---------------------------------------------------------------------------
+# the profiler sink: spans on the device trace's clock
+# ---------------------------------------------------------------------------
+
+
+def _profiled(logdir, fn):
+    """Run `fn` under a JAX profiler trace; return its host-plane events as
+    ``(name, start_ns, end_ns, stats)``."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(logdir))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(logdir), "**", "*.xplane.pb"),
+                        recursive=True)
+    with warnings.catch_warnings():
+        # reading an event's stats warns about the binding's type name
+        warnings.simplefilter("ignore", DeprecationWarning)
+        return [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                 dict(e.stats))
+                for plane in ProfileData.from_file(path).planes
+                if plane.name.startswith("/host:")
+                for line in plane.lines for e in line.events]
+
+
+def _named(events, name):
+    return [e for e in events if e[0] == name]
+
+
+def _within(inner, outer):
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def _logreg_history(n=200, d=8, steps=30):
+    from repro.core.deltagrad import sgd_train_with_cache
+    from repro.core.history import HistoryMeta
+    from repro.data.synthetic import binary_classification
+    from repro.models.simple import logreg_init, logreg_objective
+
+    ds = binary_classification(n=n, d=d, seed=0)
+    obj = logreg_objective(l2=5e-3)
+    meta = HistoryMeta(n=n, batch_size=32, seed=7, steps=steps,
+                       lr_schedule=((0, 0.3),))
+    _, hist = sgd_train_with_cache(obj, logreg_init(d, seed=1), ds, meta,
+                                   impl="scan")
+    return obj, hist, ds
+
+
+class TestProfilerSink:
+    def test_spans_land_on_the_host_plane_without_a_tracer(self, tmp_path):
+        """No tracer installed: spans are profiler events under their own
+        names, nested, with their int/str args as stats; other args (a
+        device array, a float, None) are left off, never formatted."""
+        import jax.numpy as jnp
+
+        arr = jnp.ones(3)
+
+        def work():
+            assert not obs_trace.enabled()
+            with obs_trace.span("serve.batch", batch=7, size=3, op="delete",
+                                arr=arr, pred_s=None, frac=0.5) as s:
+                assert s is not NOOP_SPAN
+                assert s.set(late=1) is s
+                with obs_trace.span("online.schedule_build", op="delete",
+                                    k=3):
+                    time.sleep(0.001)
+
+        evs = _profiled(tmp_path, work)
+        (batch,) = _named(evs, "serve.batch")
+        (build,) = _named(evs, "online.schedule_build")
+        assert _within(build, batch)
+        assert build[2] - build[1] >= 1e6
+        assert batch[3] == {"batch": 7, "size": 3, "op": "delete"}
+        assert build[3] == {"op": "delete", "k": 3}
+
+    def test_noop_again_once_the_profiler_stops(self, tmp_path):
+        _profiled(tmp_path, lambda: None)
+        assert not obs_trace.enabled()
+        assert obs_trace.span("replay.scan", t0=0, t1=8) is NOOP_SPAN
+
+    def test_tracer_and_profiler_both_record(self, tmp_path):
+        """With a tracer installed the Chrome record is as before (roofline
+        hook included) and the profiler gets the event too, without the
+        float ``pred_s``."""
+        tr = obs_trace.enable(Tracer(clock=_VirtualClock()))
+
+        def work():
+            with obs_trace.span("replay.scan", t0=0, t1=8, pred_s=2.0):
+                pass
+
+        evs = _profiled(tmp_path, work)
+        obs_trace.disable()
+        (ev,) = tr.events()
+        assert ev["args"]["roofline_ratio"] == pytest.approx(0.5)
+        (scan,) = _named(evs, "replay.scan")
+        assert scan[3] == {"t0": 0, "t1": 8}
+
+    def test_replays_under_the_profiler_emit_program_spans(self, tmp_path):
+        """A tiny batch replay and online delete, profiled with no tracer:
+        the schedule builds and host syncs are on the host plane, each sync
+        inside an explicit step or a guarded segment's replay, and no event
+        carries the roofline hook's args."""
+        import dataclasses
+
+        from repro.core.deltagrad import DeltaGradConfig, deltagrad_retrain
+        from repro.core.online import online_deltagrad
+
+        obj, hist, ds = _logreg_history()
+        cfg = dataclasses.replace(
+            DeltaGradConfig(period=5, burn_in=5, history_size=2,
+                            guard=True), impl="scan")
+
+        def work():
+            deltagrad_retrain(obj, hist, ds, np.array([3, 11]), cfg)
+            online_deltagrad(obj, hist, ds, [5, 17], cfg, mode="delete")
+
+        evs = _profiled(tmp_path, work)
+        assert len(_named(evs, "replay.schedule_build")) == 1
+        assert len(_named(evs, "online.schedule_build")) == 2
+        syncs = _named(evs, "replay.host_sync")
+        kinds = {e[3]["kind"] for e in syncs}
+        assert kinds == {"admit", "guard"}
+        explicit = _named(evs, "replay.explicit")
+        assert all(any(_within(s, x) for x in explicit)
+                   for s in syncs if s[3]["kind"] == "admit")
+        for name in ("online.request", "replay.scan", "replay.commit"):
+            assert _named(evs, name), name
+        assert not any({"pred_s", "measured_s", "roofline_ratio"}
+                       & set(e[3]) for e in evs)
+        assert not obs_trace.enabled()
+
+    def test_served_batch_spans_share_the_batch_id(self, tmp_path):
+        """A batch served through the scheduler: ``serve.batch`` carries the
+        requests' `batch_id`, and the batch's submit, plan, schedule build
+        and publish spans nest inside it."""
+        from repro.core.session import UnlearnerConfig, UnlearnerSession
+        from repro.core.deltagrad import DeltaGradConfig
+        from repro.data.synthetic import binary_classification
+        from repro.models.simple import logreg_init, logreg_objective
+        from repro.serve import ServeConfig, ServingScheduler
+
+        ds = binary_classification(n=200, d=8, seed=0)
+        sess = UnlearnerSession(
+            logreg_objective(l2=1e-3), logreg_init(8, seed=1), ds,
+            UnlearnerConfig(steps=20, batch_size=64, lr=0.2, seed=0,
+                            deltagrad=DeltaGradConfig(
+                                period=5, burn_in=5, history_size=2)))
+        sess.fit()
+        sched = ServingScheduler(sess, ServeConfig())
+        tickets = []
+
+        def work():
+            for rows in ([1, 2], [7]):
+                tickets.append(sched.submit("delete", rows=rows))
+            sched.drain()
+
+        evs = _profiled(tmp_path, work)
+        ids = {tk.req.batch_id for tk in tickets}
+        assert len(ids) == 1 and None not in ids
+        (batch,) = _named(evs, "serve.batch")
+        assert batch[3]["batch"] == ids.pop()
+        assert batch[3]["size"] == 2
+        for name in ("serve.submit", "session.plan",
+                     "online.schedule_build", "online.request",
+                     "serve.publish"):
+            (inner,) = _named(evs, name)
+            assert _within(inner, batch), name
