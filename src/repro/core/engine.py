@@ -8,7 +8,10 @@ Architecture (mapping to Wu et al., ICML 2020):
                          rates — in one vectorized pass, then uploads it to
                          the device once.  This is the paper's "replay the
                          same minibatch sequence" assumption (§A.1.2) made a
-                         data structure.
+                         data structure.  Full-batch GD's schedule is the
+                         identity on every step (`batch_in_place`), so its
+                         steps read the batch as a prefix of the device
+                         columns instead of gathering it.
 
   Phase 1  RECORD        `run_training` — Algorithm 1's original SGD run,
                          executed as a single `jax.lax.scan`; the scan's
@@ -178,13 +181,17 @@ def _scan_pred(n_params: int, steps: int, r: int, m: int,
                              hw=tracer.hw or local_hw()).pred_s
 
 
-def _publish_replay_metrics(stats: "RetrainStats", store) -> None:
+def _publish_replay_metrics(stats: "RetrainStats", store,
+                            in_place: bool) -> None:
     """Publish one finished replay's counters into the process-wide
-    `repro.obs.metrics` registry (see the contract table in `repro.obs`)."""
+    `repro.obs.metrics` registry (see the contract table in `repro.obs`).
+    `in_place`: every explicit step read its batch in place."""
     reg = obs_metrics.get_registry()
     own = "core.engine"
     reg.counter("engine.replays", owner=own).inc()
     reg.counter("engine.explicit_steps", owner=own).inc(stats.explicit_steps)
+    reg.counter("engine.explicit_in_place", owner=own).inc(
+        stats.explicit_steps if in_place else 0)
     reg.counter("engine.approx_steps", owner=own).inc(stats.approx_steps)
     reg.counter("engine.guard_fallbacks",
                 owner=own).inc(stats.guard_fallbacks)
@@ -253,6 +260,28 @@ def to_device(sched: ReplaySchedule, idx=None, lr=None) -> DeviceSchedule:
 
 def _gather(cols, rows):
     return {k: c[rows] for k, c in cols.items()}
+
+
+def batch_in_place(idx: np.ndarray, width: Optional[int] = None) -> bool:
+    """Whether a step can read its scheduled batch in place: every row of the
+    host (T, B) index matrix is ``arange(B)`` (full-batch GD) and the device
+    reads it at that width (``width``: the uploaded schedule's, which
+    `pad_schedule_batch` widens with row-0 columns).  Checked once per
+    schedule; the answer is a static argument of the jitted steps."""
+    B = idx.shape[1]
+    return (B == (B if width is None else width)
+            and bool((idx == np.arange(B)).all()))
+
+
+def _read_batch(cols, rows, in_place: bool):
+    """A step's scheduled batch: the columns gathered at `rows`, or, for an
+    identity schedule (`batch_in_place`), their first ``len(rows)`` rows as a
+    static slice, which XLA reads without a copy.  Device columns may be
+    padded past the schedule's width (`Dataset.device_columns(capacity)`)."""
+    if not in_place:
+        return _gather(cols, rows)
+    B = rows.shape[0]
+    return {k: c if c.shape[0] == B else c[:B] for k, c in cols.items()}
 
 
 # --------------------------------------------------------------------------
@@ -444,13 +473,13 @@ def _combine_explicit(g_kept, g_changed, k, dB, B, sign: int):
 # --------------------------------------------------------------------------
 
 
-@partial(jax.jit, static_argnames=("grad_fn", "momentum"))
+@partial(jax.jit, static_argnames=("grad_fn", "momentum", "in_place"))
 def _train_scan(params0, vel0, cols, idx, lr, w_ones, mom, *, grad_fn,
-                momentum: bool):
+                momentum: bool, in_place: bool):
     def body(carry, xs):
         params, vel = carry
         rows, lr_t = xs
-        g = grad_fn(params, _gather(cols, rows), w_ones)
+        g = grad_fn(params, _read_batch(cols, rows, in_place), w_ones)
         if momentum:
             new_p, new_vel = _momentum_math(params, vel, g, lr_t, mom)
         else:
@@ -514,6 +543,7 @@ def run_training(
     lr_dev = jnp.asarray(lrs)
     ones = jnp.ones((B,), jnp.float32)
     mom = jnp.float32(meta.momentum)
+    in_place = batch_in_place(idx_all)
 
     if tier in ("host", "disk"):
         # offload tiers keep the full path OUT of device memory, but the
@@ -527,7 +557,7 @@ def run_training(
             b = min(meta.steps, a + L)
             params, vel, Ws, Gs = _train_scan(
                 params, vel, cols, idx_dev[a:b], lr_dev[a:b], ones, mom,
-                grad_fn=grad_fn, momentum=momentum)
+                grad_fn=grad_fn, momentum=momentum, in_place=in_place)
             host_w, host_g = jax.device_get((Ws, Gs))
             for i in range(b - a):
                 history.append(jax.tree.map(lambda x: x[i], host_w),
@@ -537,7 +567,7 @@ def run_training(
 
     params, _, Ws, Gs = _train_scan(
         params0, vel, cols, idx_dev, lr_dev, ones, mom, grad_fn=grad_fn,
-        momentum=momentum)
+        momentum=momentum, in_place=in_place)
     history.set_stacked(Ws, Gs, final_params=params)
     return params, history
 
@@ -547,18 +577,19 @@ def run_training(
 # --------------------------------------------------------------------------
 
 
-@partial(jax.jit, static_argnames=("grad_fn", "momentum", "mode"))
+@partial(jax.jit, static_argnames=("grad_fn", "momentum", "mode",
+                                   "in_place"))
 def _baseline_scan(params0, vel0, cols, sd: DeviceSchedule, mom, *, grad_fn,
-                   momentum: bool, mode: str):
+                   momentum: bool, mode: str, in_place: bool):
     def body(carry, t):
         params, vel = carry
-        if mode == "delete":
-            batch = _gather(cols, sd.idx[t])
-            w = sd.kept_w[t]
-        else:
-            batch = {k: jnp.concatenate([c[sd.idx[t]], c[sd.changed_idx[t]]])
-                     for k, c in cols.items()}
-            w = jnp.concatenate([sd.kept_w[t], sd.changed_w[t]])
+        batch = _read_batch(cols, sd.idx[t], in_place)
+        w = sd.kept_w[t]
+        if mode == "add":
+            joined = _gather(cols, sd.changed_idx[t])
+            batch = {k: jnp.concatenate([c, joined[k]])
+                     for k, c in batch.items()}
+            w = jnp.concatenate([w, sd.changed_w[t]])
         g = grad_fn(params, batch, w)
         if momentum:
             new_p, new_vel = _momentum_math(params, vel, g, sd.lr[t], mom)
@@ -634,7 +665,8 @@ def run_baseline(
     vel = _tree_zeros(params0) if momentum else None
     params = _baseline_scan(params0, vel, ds.device_columns(),
                             to_device(sched), jnp.float32(meta.momentum),
-                            grad_fn=grad_fn, momentum=momentum, mode=mode)
+                            grad_fn=grad_fn, momentum=momentum, mode=mode,
+                            in_place=batch_in_place(sched.idx))
     jax.block_until_ready(params)
     stats.wall_time_s = time.perf_counter() - t0
     return params, stats
@@ -777,6 +809,7 @@ def run_replay(
         gather = runner.gather_info()
         axis = runner.placement.data_axis
         n_shards = runner.placement.data_size
+    in_place = batch_in_place(sched.idx, sd.idx.shape[1])
     cols = ds.device_columns()
     buffer = LbfgsBuffer(cfg.history_size, curvature_eps=cfg.curvature_eps)
 
@@ -818,7 +851,7 @@ def run_replay(
             return _host_explicit_step(
                 grad_fn, buffer, p, v, tt, store, cols, sd,
                 float(sched.kept[tt]), float(sched.dB[tt]), Bf, mom, sign,
-                momentum, stats)
+                momentum, in_place, stats)
 
     t = 0
     while t < T:
@@ -909,20 +942,23 @@ def run_replay(
         stats.extra["spill_io_write_s"] = history.io_write_s
     if runner is not None:
         stats.extra["mesh"] = runner.placement.describe()
-    _publish_replay_metrics(stats, store)
+    _publish_replay_metrics(stats, store, in_place)
     return params, stats
 
 
-@partial(jax.jit, static_argnames=("grad_fn", "sign", "momentum"))
+@partial(jax.jit, static_argnames=("grad_fn", "sign", "momentum",
+                                   "in_place"))
 def _explicit_step(params, vel, t, w_t, g_t, cols, sd: DeviceSchedule, B,
-                   mom, *, grad_fn, sign: int, momentum: bool):
+                   mom, *, grad_fn, sign: int, momentum: bool,
+                   in_place: bool):
     """The whole explicit step as ONE program: kept + changed gradients
     against the store-served (w_t, g_t) history entry, pair construction
     (with the Algorithm-4 admission inner products), and the parameter
     update.  The host only syncs the two admission scalars — one
     round-trip per explicit step."""
     k, dB, lr = sd.kept[t], sd.dB[t], sd.lr[t]
-    g_kept = grad_fn(params, _gather(cols, sd.idx[t]), sd.kept_w[t])
+    g_kept = grad_fn(params, _read_batch(cols, sd.idx[t], in_place),
+                     sd.kept_w[t])
     has = (dB > 0).astype(jnp.float32)
     g_changed = jax.tree.map(
         lambda x: has * x,
@@ -939,12 +975,12 @@ def _explicit_step(params, vel, t, w_t, g_t, cols, sd: DeviceSchedule, B,
 
 
 def _host_explicit_step(grad_fn, buffer, params, vel, t, store, cols, sd,
-                        k, dB, Bf, mom, sign, momentum, stats):
+                        k, dB, Bf, mom, sign, momentum, in_place, stats):
     """One explicit step (host-driven: it mutates the L-BFGS buffer)."""
     w_t, g_t = store.entry(t)
     params, vel, dw, dg, admit = _explicit_step(
         params, vel, t, w_t, g_t, cols, sd, Bf, mom, grad_fn=grad_fn,
-        sign=sign, momentum=momentum)
+        sign=sign, momentum=momentum, in_place=in_place)
     with obs_trace.span("replay.host_sync", kind="admit"):
         curv, ss = np.asarray(admit)
     if not buffer.add_pair(dw, dg, float(curv), float(ss)):
@@ -1186,10 +1222,11 @@ _online_segment = partial(jax.jit, static_argnames=(
     "grad_fn", "sign", "momentum", "span", "gather"))(_online_segment_impl)
 
 
-@partial(jax.jit, static_argnames=("grad_fn", "sign", "momentum"))
+@partial(jax.jit, static_argnames=("grad_fn", "sign", "momentum",
+                                   "in_place"))
 def _online_explicit_step(params, vel, t, w_t, g_t, cols,
                           sd: DeviceSchedule, mom, *, grad_fn, sign: int,
-                          momentum: bool):
+                          momentum: bool, in_place: bool):
     """Online explicit step fused into one program: kept and changed-row
     gradients against the store-served history entry, the pre/post-request
     gradient pair, and the update.  Only the two L-BFGS admission scalars
@@ -1197,7 +1234,8 @@ def _online_explicit_step(params, vel, t, w_t, g_t, cols,
     the caller can batch it into the end-of-request flush instead of
     scattering per step."""
     kept, dB, lr = sd.kept[t], sd.dB[t], sd.lr[t]
-    g_base = grad_fn(params, _gather(cols, sd.idx[t]), sd.kept_w[t])
+    g_base = grad_fn(params, _read_batch(cols, sd.idx[t], in_place),
+                     sd.kept_w[t])
     has = (dB > 0).astype(jnp.float32)
     g_one = jax.tree.map(
         lambda x: has * x,
@@ -1227,10 +1265,12 @@ def _ring_append(dWs, dGs, dw, dg, admit, eps):
     return dWs, dGs
 
 
-@partial(jax.jit, static_argnames=("grad_fn", "sign", "momentum"))
+@partial(jax.jit, static_argnames=("grad_fn", "sign", "momentum",
+                                   "in_place"))
 def _online_explicit_fused(params, vel, t, w_t, g_t, cols,
                            sd: DeviceSchedule, dWs, dGs, eps, mom, *,
-                           grad_fn, sign: int, momentum: bool):
+                           grad_fn, sign: int, momentum: bool,
+                           in_place: bool):
     """`_online_explicit_step` with the Algorithm-4 pair admission resolved
     ON DEVICE via `_ring_append` — every explicit step (burn-in included)
     runs this fused program against the zeros-initialized ring, so an
@@ -1241,7 +1281,7 @@ def _online_explicit_fused(params, vel, t, w_t, g_t, cols,
     engine."""
     new_p, new_vel, g_cur, dw, dg, admit = _online_explicit_step(
         params, vel, t, w_t, g_t, cols, sd, mom, grad_fn=grad_fn, sign=sign,
-        momentum=momentum)
+        momentum=momentum, in_place=in_place)
     dWs, dGs = _ring_append(dWs, dGs, dw, dg, admit, eps)
     return new_p, new_vel, g_cur, dWs, dGs
 
@@ -1290,6 +1330,7 @@ def run_online_request(
     if runner is not None:
         sd = pad_schedule_batch(sd, runner.placement.data_size)
         gather = runner.gather_info()
+    in_place = batch_in_place(sched.idx, sd.idx.shape[1])
     if seg_grad_fn is None:
         seg_grad_fn = grad_fn
     params = store.params0()  # w_0 is never rewritten
@@ -1358,7 +1399,8 @@ def run_online_request(
                 w_t, g_t = store.entry(tt)
                 params, vel, g_cur, dWs, dGs = _online_explicit_fused(
                     params, vel, tt, w_t, g_t, cols, sd, dWs, dGs, eps,
-                    mom, grad_fn=grad_fn, sign=sign, momentum=momentum)
+                    mom, grad_fn=grad_fn, sign=sign, momentum=momentum,
+                    in_place=in_place)
                 note_single(tt, p_in, g_cur)
         ring_started = True
         stats.grad_examples += int(
@@ -1469,5 +1511,5 @@ def run_online_request(
     # the engine pops it off extra so logged stats stay device-array-free
     if ring_started:
         stats.extra["lbfgs_ring"] = (dWs, dGs)
-    _publish_replay_metrics(stats, store)
+    _publish_replay_metrics(stats, store, in_place)
     return params, stats

@@ -76,6 +76,7 @@ publishes it:
     ---------------------------- ---------- ----- ---------------------
     engine.replays               counter    1     core.engine
     engine.explicit_steps        counter    1     core.engine
+    engine.explicit_in_place     counter    1     core.engine
     engine.approx_steps          counter    1     core.engine
     engine.guard_fallbacks       counter    1     core.engine
     engine.grad_examples         counter    1     core.engine

@@ -8,19 +8,28 @@ the RetrainStats counters must agree exactly.
 
 import dataclasses
 
+import jax
 import numpy as np
 import pytest
 
+from repro.core import engine
 from repro.core.deltagrad import (
     DeltaGradConfig,
     baseline_retrain,
     deltagrad_retrain,
     sgd_train_with_cache,
 )
+from repro.core.engine import (_next_pow2, batch_in_place,
+                               run_online_request, to_device)
 from repro.core.history import HistoryMeta, TrainingHistory
-from repro.core.online import online_deltagrad
-from repro.data.synthetic import binary_classification
-from repro.models.simple import logreg_init, logreg_objective
+from repro.core.online import OnlineEngine, online_deltagrad
+from repro.core.store import pad_schedule_batch
+from repro.data.sampler import (addition_mask_all, build_online_schedule,
+                                build_schedule)
+from repro.data.synthetic import (binary_classification,
+                                  multiclass_classification)
+from repro.models.simple import (logreg_init, logreg_objective, mlp_init,
+                                 mlp_objective)
 from repro.utils.tree import tree_norm, tree_sub
 
 TOL = 1e-5
@@ -272,6 +281,122 @@ class TestOnlineParity:
             assert a.approx_steps == b.approx_steps
         for t in (3, meta.steps - 1):
             assert _dist(h1.entry(t)[1], h2.entry(t)[1]) < TOL
+
+
+_STAT_FIELDS = ("explicit_steps", "approx_steps", "guard_fallbacks",
+                "skipped_steps", "pairs_rejected", "grad_examples",
+                "grad_examples_baseline")
+
+
+def _full_batch_mlp(n=256, d=12, steps=30):
+    """The paper's model family (ReLU MLP) under full-batch GD, tiny.  Its
+    matmuls compile to the same library dot on the CPU whether their rows
+    were gathered or read in place; a matrix-VECTOR model (binary logreg)
+    instead gets a loop-fused dot on the gather path, equal to round-off
+    only."""
+    ds = multiclass_classification(n=n, d=d, num_classes=4, seed=0)
+    meta = HistoryMeta(n=n, batch_size=1 << 30, seed=7, steps=steps,
+                       lr_schedule=((0, 0.3), (10, 0.1)))
+    return ds, mlp_objective(), meta, mlp_init(d, 16, 4, seed=1)
+
+
+def _in_place_case(case):
+    """(the engine's identity check for the case's schedule, and for an
+    identity schedule a run returning (params, RetrainStats))."""
+    ds, obj, meta, p0 = _full_batch_mlp()
+    rows = np.arange(5, 15)
+    cfg = dataclasses.replace(CFG, guard=True)
+    sched = build_schedule(meta.seed, meta.steps, meta.n, meta.batch_size,
+                           rows, "delete", 16, meta.lr_at)
+    if case == "record":
+        return (batch_in_place(sched.idx),
+                lambda: sgd_train_with_cache(obj, p0, ds, meta))
+    if case == "baseline":
+        return (batch_in_place(sched.idx),
+                lambda: baseline_retrain(obj, ds, meta, p0, rows))
+    _, hist = sgd_train_with_cache(obj, p0, ds, meta)
+    if case == "delete":
+        return (batch_in_place(sched.idx),
+                lambda: deltagrad_retrain(obj, hist, ds, rows, cfg))
+    if case == "add":
+        # the appended rows widen the device columns past the schedule's
+        # width: the in-place read is a prefix slice
+        new = ds.append({k: v[rows] for k, v in ds.columns.items()})
+        add = build_schedule(meta.seed, meta.steps, meta.n, meta.batch_size,
+                             new, "add", 16, meta.lr_at)
+        return (batch_in_place(add.idx),
+                lambda: deltagrad_retrain(obj, hist, ds, new, cfg,
+                                          mode="add"))
+    eng = OnlineEngine(obj, hist, ds, cfg)
+    if case == "online-group":
+        group = eng._schedule("delete", rows.tolist())
+
+        def serve():  # a request rewrites the path: serve a fresh one
+            ds_, obj_, _, _ = _full_batch_mlp()
+            _, hist_ = sgd_train_with_cache(obj_, p0, ds_, meta)
+            fresh = OnlineEngine(obj_, hist_, ds_, cfg)
+            st = fresh.request_group("delete", rows.tolist())
+            return fresh.params, st
+
+        return batch_in_place(group.idx), serve
+    if case == "online-pow2-columns":
+        group = eng._schedule("delete", rows.tolist())
+        cols = ds.device_columns(capacity=_next_pow2(ds.n + 1))
+        assert next(iter(cols.values())).shape[0] > group.batch
+        return (batch_in_place(group.idx),
+                lambda: run_online_request(
+                    eng.grad_fn, eng.store, cols, group, cfg,
+                    static_dev=eng._static_dev(group), commit=False))
+    if case == "minibatch":
+        mini = build_schedule(meta.seed, meta.steps, meta.n, 64, rows,
+                              "delete", 16, meta.lr_at)
+        return batch_in_place(mini.idx), None
+    if case == "add-join-columns":
+        # three earlier additions ride a pow2 block of 4 join columns, the
+        # last of them padding that points at row 0
+        ds.append({k: v[:4] for k, v in ds.columns.items()})
+        live = np.ones(ds.n, dtype=bool)
+        joins = addition_mask_all(meta.seed, meta.steps, meta.n,
+                                  meta.batch_size, 4)
+        ext = build_online_schedule(
+            meta.seed, meta.steps, meta.n, meta.batch_size, [meta.n + 3],
+            "add", meta.lr_at, live, np.arange(meta.n, meta.n + 3), joins,
+            4)
+        return batch_in_place(ext.idx), None
+    assert case == "shard-padded", case
+    # batch sharding over 7 devices pads 256 columns to 259 (row-0 fill)
+    padded = pad_schedule_batch(to_device(sched), 7)
+    return batch_in_place(sched.idx, padded.idx.shape[1]), None
+
+
+class TestInPlaceBatch:
+    @pytest.mark.parametrize("case, identity", [
+        ("record", True), ("baseline", True), ("delete", True),
+        ("add", True), ("online-group", True),
+        ("online-pow2-columns", True), ("minibatch", False),
+        ("add-join-columns", False), ("shard-padded", False)])
+    def test_identity_schedule_reads_batch_in_place(self, case, identity,
+                                                    monkeypatch):
+        """Full-batch GD's schedule is the identity on every step, so each
+        step reads the first B rows of the columns in place instead of
+        gathering them; the numbers must be bitwise the gather path's, and
+        every other schedule keeps the gather."""
+        check, run = _in_place_case(case)
+        assert check is identity
+        if run is None:
+            return
+        w_in, st_in = run()
+        monkeypatch.setattr(engine, "batch_in_place",
+                            lambda idx, width=None: False)
+        w_g, st_g = run()
+        if isinstance(st_in, TrainingHistory):  # the recorded path too
+            w_in = (w_in, st_in.stacked_view())
+            w_g = (w_g, st_g.stacked_view())
+        else:
+            for f in _STAT_FIELDS:
+                assert getattr(st_in, f) == getattr(st_g, f), f
+        for a, b in zip(jax.tree.leaves(w_in), jax.tree.leaves(w_g)):
+            assert np.array_equal(np.asarray(a), np.asarray(b)), case
 
 
 class TestStackedTier:

@@ -353,6 +353,44 @@ class TestReplayInstrumentation:
         # the commit span closes out every online replay
         assert any(e["name"] == "replay.commit" for e in tr.events())
 
+    @pytest.mark.parametrize("batch", [200, 32])
+    def test_explicit_in_place_counts_identity_schedules(self, batch):
+        """``engine.explicit_in_place`` counts every explicit step of a
+        full-batch replay (batch >= n: each step's batch is read in place)
+        and none of a minibatch replay, on the batch and online paths."""
+        import dataclasses
+
+        from repro.core.deltagrad import (DeltaGradConfig, deltagrad_retrain,
+                                          sgd_train_with_cache)
+        from repro.core.history import HistoryMeta
+        from repro.core.online import online_deltagrad
+        from repro.data.synthetic import binary_classification
+        from repro.models.simple import logreg_init, logreg_objective
+
+        n, d = 200, 8
+        ds = binary_classification(n=n, d=d, seed=0)
+        obj = logreg_objective(l2=5e-3)
+        meta = HistoryMeta(n=n, batch_size=batch, seed=7, steps=30,
+                           lr_schedule=((0, 0.3),))
+        _, hist = sgd_train_with_cache(obj, logreg_init(d, seed=1), ds, meta)
+        cfg = dataclasses.replace(
+            DeltaGradConfig(period=5, burn_in=5, history_size=2,
+                            guard=True), impl="scan")
+        old = obs_metrics.get_registry()
+        reg = obs_metrics.set_registry(MetricsRegistry())
+        try:
+            _, st = deltagrad_retrain(obj, hist, ds, np.array([3, 11]), cfg)
+            _, ost = online_deltagrad(obj, hist, ds, [5, 17], cfg,
+                                      mode="delete")
+        finally:
+            obs_metrics.set_registry(old)
+        explicit = st.explicit_steps + sum(
+            r.explicit_steps for r in ost.per_request)
+        assert explicit > 0
+        assert reg.counter("engine.explicit_steps").value == explicit
+        assert reg.counter("engine.explicit_in_place").value == (
+            explicit if batch >= n else 0)
+
     def test_serve_trace_out_refuses_a_device_without_peaks(self, tmp_path):
         """`serve unlearn --trace-out` prices replay spans for the device
         that runs them; on one with no peak rates (the CPU) it stops at
