@@ -87,7 +87,8 @@ class Objective:
     @classmethod
     def from_model(cls, model, *, remat: bool = False,
                    loss_chunk: Optional[int] = None, l2: float = 0.0,
-                   attn_impl: Optional[str] = None) -> "Objective":
+                   attn_impl: Optional[str] = None, dtype=None,
+                   precision: Optional[str] = None) -> "Objective":
         """Build an Objective from a `models.registry.Model`.
 
         The model's ``loss_fn(params, batch) -> ()`` is a mean loss over a
@@ -103,10 +104,18 @@ class Objective:
         implementation (`models.attention_config`) for every trace of
         this objective: ``"flash"`` routes the Pallas flash kernel onto
         the replay forward where shapes allow.
+
+        ``dtype`` is the loss's compute dtype (the model's default, bf16
+        for the LMs, when None) and ``precision`` the matmul precision its
+        trace asks for (``"highest"``: f32 products on a TPU, whose default
+        f32 dot rounds its inputs to bf16).  An f32 path, history and
+        L-BFGS pairs need both.
         """
         kw: Dict[str, Any] = {"remat": remat}
         if loss_chunk is not None:
             kw["loss_chunk"] = loss_chunk
+        if dtype is not None:
+            kw["dtype"] = dtype
 
         def per_example_loss(params, batch):
             from repro.models.attention_config import use_attention_impl
@@ -115,7 +124,8 @@ class Objective:
                 return model.loss_fn(
                     params, jax.tree.map(lambda c: c[None], row), **kw)
 
-            with use_attention_impl(attn_impl):
+            with use_attention_impl(attn_impl), \
+                    jax.default_matmul_precision(precision):
                 return jax.vmap(one)(batch)
 
         return cls(per_example_loss=per_example_loss, l2=l2)
@@ -137,11 +147,13 @@ def sgd_train_with_cache(
     impl: str = "scan",
     window: int = 0,
     spill_window: Optional[int] = None,
+    placement=None,
 ) -> Tuple[Any, TrainingHistory]:
-    """Train w_t by plain SGD (the paper's optimizer), caching (w_t, g_t)."""
+    """Train w_t by plain SGD (the paper's optimizer), caching (w_t, g_t);
+    `placement` as in `core.engine.run_training`."""
     return run_training(objective, params0, ds, meta, tier=tier, codec=codec,
                         spill_dir=spill_dir, impl=impl, window=window,
-                        spill_window=spill_window)
+                        spill_window=spill_window, placement=placement)
 
 
 def baseline_retrain(

@@ -64,13 +64,12 @@ Architecture (mapping to Wu et al., ICML 2020):
                          on the same flattened operands.
 
 Where the history bytes live is `core.store`'s concern: stacked/device
-tiers replay fully resident (optionally sharded across a mesh, with the
-segment scans run under ``shard_map`` and per-example gradients
-psum-reduced), host/disk tiers stream double-buffered segment windows to
-the same compiled scans — and the two COMPOSE: a mesh-placed host/disk
-tier streams per-shard encoded window segments (`ShardedStreamer`), the
-scans consuming them under shard_map exactly like the resident sharded
-path (window-granular gather source, same per-step all-gather plan).
+tiers replay fully resident (optionally sharded across a mesh, the
+segment scans then plain jits over the sharded state, `PlacedReplay`),
+host/disk tiers stream double-buffered segment windows to the same
+compiled scans — and the two COMPOSE: a mesh-placed host/disk tier
+streams per-shard encoded window segments (`ShardedStreamer`), consumed
+exactly like the resident sharded path.
 Execution backends: ``impl="scan"`` (this
 module's compiled path, all tiers) and ``impl="python"`` (the pre-refactor
 per-step loop, kept as the parity oracle).  Numerics and counters
@@ -105,8 +104,8 @@ from jax.flatten_util import ravel_pytree
 from repro.core.history import HistoryMeta, TrainingHistory
 from repro.core.lbfgs import LbfgsBuffer, lbfgs_hvp_stacked_pytree
 from repro.core.store import (EncodedLeaf, HistoryStore, auto_window,
-                              entry_at, is_encoded_window,
-                              make_psum_grad_fn, pad_schedule_batch)
+                              constrain, entry_at, is_encoded_window,
+                              per_shard)
 from repro.data.dataset import Dataset
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -265,9 +264,9 @@ def _gather(cols, rows):
 def batch_in_place(idx: np.ndarray, width: Optional[int] = None) -> bool:
     """Whether a step can read its scheduled batch in place: every row of the
     host (T, B) index matrix is ``arange(B)`` (full-batch GD) and the device
-    reads it at that width (``width``: the uploaded schedule's, which
-    `pad_schedule_batch` widens with row-0 columns).  Checked once per
-    schedule; the answer is a static argument of the jitted steps."""
+    reads it at that width (``width``: the uploaded schedule's).  Checked
+    once per schedule; the answer is a static argument of the jitted
+    steps."""
     B = idx.shape[1]
     return (B == (B if width is None else width)
             and bool((idx == np.arange(B)).all()))
@@ -334,85 +333,91 @@ def _run_fused(w, g, b, c, lr, B, dB, s, fused: str):
 
 
 def _flat_fused_update(params, g_t, bv, g_changed, lr, B, dB, sign: int,
-                       fused: str, axis: Optional[str] = None,
-                       n_shards: int = 1):
+                       fused: str, shards=None):
     """Paper eq. (2)/(S7) on the FLATTENED parameter vector, through the
     Pallas fused kernel (TPU), its interpret mode, or the jnp reference —
     all three compute w - lr/(B - sign*dB) * (B*(g_t + Bv) - sign*dB*g_c).
 
-    Inside a shard_map body (`axis` set), the kernel is routed PER SHARD:
-    each mesh member along `axis` runs the fused op on its 1/n_shards tile
-    of the flattened vector and the tiles all-gather back — the update is
-    elementwise, so the split is exact."""
+    With the parameters placed on a mesh (`shards`,
+    `core.store.PlacedReplay.kernel_shards`) nothing is flattened across
+    leaves: each leaf's kernel runs on every device's block of it."""
+    s = jnp.float32(sign)
+    if shards is not None:
+        leaves, tdef = jax.tree.flatten(params)
+        ops = zip(leaves, jax.tree.leaves(g_t), jax.tree.leaves(bv),
+                  jax.tree.leaves(g_changed))
+        flat = lambda x: x.reshape(-1)
+        return jax.tree.unflatten(tdef, [
+            per_shard(lambda lr, B, dB, w, g, b, c: _run_fused(
+                flat(w), flat(g), flat(b), flat(c), lr, B, dB, s,
+                fused).reshape(w.shape), shards, i, (lr, B, dB), xs)
+            for i, xs in enumerate(ops)])
     w, unravel = ravel_pytree(params)
     g, _ = ravel_pytree(g_t)
     b, _ = ravel_pytree(bv)
     c, _ = ravel_pytree(g_changed)
-    s = jnp.float32(sign)
-    if axis is not None and n_shards > 1:
-        p = w.shape[0]
-        pp = -(-p // n_shards) * n_shards
-        ps = pp // n_shards
-        i = jax.lax.axis_index(axis)
-
-        def cut(x):
-            return jax.lax.dynamic_slice(jnp.pad(x, (0, pp - p)),
-                                         (i * ps,), (ps,))
-
-        out = _run_fused(cut(w), cut(g), cut(b), cut(c), lr, B, dB, s,
-                         fused)
-        out = jax.lax.all_gather(out, axis, axis=0, tiled=True)[:p]
-    else:
-        out = _run_fused(w, g, b, c, lr, B, dB, s, fused)
-    return unravel(out)
+    return unravel(_run_fused(w, g, b, c, lr, B, dB, s, fused))
 
 
 def _enc_slice_args(leaf: EncodedLeaf, i):
-    """(q, scale, base) of step ``i`` of one encoded window leaf, flattened
-    for the `kernels.dequant_update` ops (scale is per (leaf, step), which
-    is why the fused dequant kernels route PER LEAF)."""
-    q = leaf.q[i].reshape(-1)
+    """(q, scale, base) of step ``i`` of one encoded window leaf, in the
+    leaf's shape, for the `kernels.dequant_update` ops (scale is per
+    (leaf, step), which is why the fused dequant kernels route PER LEAF)."""
+    q = leaf.q[i]
     scale = leaf.scale[i] if leaf.scale is not None else jnp.float32(1.0)
-    base = None if leaf.base is None \
-        else leaf.base[leaf.kidx[i]].reshape(-1)
+    base = None if leaf.base is None else leaf.base[leaf.kidx[i]]
     return q, scale, base
 
 
-def _dequant_sub_tree(params, W, i, fused: str):
+def _leafwise(fn, params, *trees):
+    """``fn(k, p, *xs)`` over the parameter leaves, `k` the leaf's index
+    (`W`/`G` windows flatten their `EncodedLeaf` leaves whole)."""
+    leaves, tdef = jax.tree.flatten(params)
+    cols = [jax.tree.leaves(t, is_leaf=lambda x: isinstance(x, EncodedLeaf))
+            for t in trees]
+    return jax.tree.unflatten(tdef, [fn(k, p, *xs) for k, (p, *xs)
+                                     in enumerate(zip(leaves, *cols))])
+
+
+def _dequant_sub_tree(params, W, i, fused: str, shards=None):
     """``v = params - w_t`` with the cached parameter operand consumed
     ENCODED — the `dequant_sub` Pallas kernel dequantizes in registers, so
-    no f32 copy of w_t is ever materialized."""
+    no f32 copy of w_t is ever materialized.  `shards` routes each leaf's
+    call per device (`core.store.per_shard`)."""
     from repro.kernels.dequant_update.ops import dequant_sub
 
-    def one(p, leaf):
+    def one(k, p, leaf):
         if not isinstance(leaf, EncodedLeaf):
             return p - leaf[i]
         q, scale, base = _enc_slice_args(leaf, i)
-        out = dequant_sub(p.reshape(-1), q, scale, base,
-                          interpret=fused == "interpret")
-        return out.reshape(p.shape)
+        kern = lambda scale, p, q, *base: dequant_sub(
+            p, q, scale, *base, interpret=fused == "interpret")
+        return per_shard(kern, shards, k, (scale,),
+                         (p, q) + (() if base is None else (base,)))
 
-    return jax.tree.map(one, params, W)
+    return _leafwise(one, params, W)
 
 
 def _dequant_fused_update(params, G, i, bv, g_changed, lr, B, dB, sign: int,
-                          fused: str):
+                          fused: str, shards=None):
     """The non-momentum approx update with the cached gradient operand
     consumed ENCODED — `dequant_update` fuses the dequant with the
-    leave-r-out step, per leaf (per-leaf scales)."""
+    leave-r-out step, per leaf (per-leaf scales), and per device with
+    `shards`."""
     from repro.kernels.dequant_update.ops import dequant_update
 
-    def one(p, leaf, b, c):
+    def one(k, p, leaf, b, c):
         if not isinstance(leaf, EncodedLeaf):
             denom = jnp.maximum(B - sign * dB, 1.0)
             return p - lr * (B * (leaf[i] + b) - sign * dB * c) / denom
         q, scale, base = _enc_slice_args(leaf, i)
-        out = dequant_update(p.reshape(-1), q, b.reshape(-1), c.reshape(-1),
-                             lr, B, dB, sign, scale, base,
-                             interpret=fused == "interpret")
-        return out.reshape(p.shape)
+        kern = lambda lr, B, dB, scale, p, q, b, c, *base: dequant_update(
+            p, q, b, c, lr, B, dB, sign, scale, *base,
+            interpret=fused == "interpret")
+        return per_shard(kern, shards, k, (lr, B, dB, scale),
+                         (p, q, b, c) + (() if base is None else (base,)))
 
-    return jax.tree.map(one, params, G, bv, g_changed)
+    return _leafwise(one, params, G, bv, g_changed)
 
 
 def _approx_math(g_t, bv, g_changed, B, dB, sign: int):
@@ -473,18 +478,19 @@ def _combine_explicit(g_kept, g_changed, k, dB, B, sign: int):
 # --------------------------------------------------------------------------
 
 
-@partial(jax.jit, static_argnames=("grad_fn", "momentum", "in_place"))
+@partial(jax.jit, static_argnames=("grad_fn", "momentum", "in_place", "pin"))
 def _train_scan(params0, vel0, cols, idx, lr, w_ones, mom, *, grad_fn,
-                momentum: bool, in_place: bool):
+                momentum: bool, in_place: bool, pin=None):
     def body(carry, xs):
         params, vel = carry
         rows, lr_t = xs
-        g = grad_fn(params, _read_batch(cols, rows, in_place), w_ones)
+        g = constrain(grad_fn(params, _read_batch(cols, rows, in_place),
+                              w_ones), pin)
         if momentum:
             new_p, new_vel = _momentum_math(params, vel, g, lr_t, mom)
         else:
             new_p, new_vel = _sgd_math(params, g, lr_t), vel
-        return (new_p, new_vel), (params, g)
+        return (constrain(new_p, pin), new_vel), (params, g)
 
     (pT, velT), (Ws, Gs) = jax.lax.scan(body, (params0, vel0), (idx, lr))
     return pT, velT, Ws, Gs
@@ -501,6 +507,7 @@ def run_training(
     impl: str = "scan",
     window: int = 0,
     spill_window: Optional[int] = None,
+    placement=None,
 ) -> Tuple[Any, TrainingHistory]:
     """Train w_t by plain SGD (the paper's optimizer), caching (w_t, g_t).
 
@@ -509,7 +516,12 @@ def run_training(
     `core.store.SegmentStreamer` uses on the read path).  On the disk
     tier, spills batch ONE .npz per ``spill_window`` steps (None → match
     the stream window; 1 → the legacy one-file-per-step layout, which
-    stays readable either way)."""
+    stays readable either way).
+
+    ``placement`` (a `core.store.PlacementPolicy`) is recorded on the
+    history, so stores built for it take its mesh; a mesh that shards the
+    model's state (`PlacementPolicy.shards_state`) also trains with the
+    parameters, gradients and recorded windows sharded."""
     grad_fn = objective.make_grad_fn()
     momentum = bool(meta.momentum)
     vel = _tree_zeros(params0) if momentum else None
@@ -519,6 +531,12 @@ def run_training(
             else 0
     history = TrainingHistory(meta, tier=tier, codec=codec,
                               spill_dir=spill_dir, spill_window=spill_window)
+    history.placement = placement
+    pin = None
+    if placement is not None and placement.shards_state and impl != "python":
+        shardings = placement.param_shardings(params0)
+        params0 = jax.device_put(params0, shardings)
+        pin = tuple(jax.tree.leaves(shardings))
 
     if impl == "python":
         ones = np.ones(B, dtype=np.float32)
@@ -553,21 +571,25 @@ def run_training(
         # path mirrors this via core.store.SegmentStreamer)
         L = auto_window(meta.steps, window)
         params = params0
+        pending = None
         for a in range(0, meta.steps, L):
             b = min(meta.steps, a + L)
             params, vel, Ws, Gs = _train_scan(
                 params, vel, cols, idx_dev[a:b], lr_dev[a:b], ones, mom,
-                grad_fn=grad_fn, momentum=momentum, in_place=in_place)
-            host_w, host_g = jax.device_get((Ws, Gs))
-            for i in range(b - a):
-                history.append(jax.tree.map(lambda x: x[i], host_w),
-                               jax.tree.map(lambda x: x[i], host_g))
+                grad_fn=grad_fn, momentum=momentum, in_place=in_place,
+                pin=pin)
+            # the previous window's encoded entries cross to the host
+            # while this one trains
+            history.land_window(pending)
+            pending = history.encode_window(Ws, Gs)
+            del Ws, Gs
+        history.land_window(pending)
         history.finalize(params)
         return params, history
 
     params, _, Ws, Gs = _train_scan(
         params0, vel, cols, idx_dev, lr_dev, ones, mom, grad_fn=grad_fn,
-        momentum=momentum, in_place=in_place)
+        momentum=momentum, in_place=in_place, pin=pin)
     history.set_stacked(Ws, Gs, final_params=params)
     return params, history
 
@@ -677,11 +699,22 @@ def run_baseline(
 # --------------------------------------------------------------------------
 
 
+def _changed_grad(grad_fn, params, cols, sd: DeviceSchedule, t):
+    """The gradient over step `t`'s changed rows, or zeros when the batch
+    holds none of them: the padded changed-row batch is only computed when
+    it carries weight (at LM scale it costs as much as the step's own
+    batch)."""
+    return jax.lax.cond(
+        sd.dB[t] > 0,
+        lambda p: grad_fn(p, _gather(cols, sd.changed_idx[t]),
+                          sd.changed_w[t]),
+        lambda p: jax.tree.map(jnp.zeros_like, p), params)
+
+
 def _replay_segment_impl(params, vel, t0, off, W, G, cols,
                          sd: DeviceSchedule, dWs, dGs, B, clip, mom, *,
                          grad_fn, sign: int, momentum: bool, fused: str,
-                         span: int, gather=None, axis=None,
-                         n_shards: int = 1):
+                         span: int, shards=None):
     """One approx segment [t0, t0+span) as a single scan.
 
     Per step: dynamic-slice (w_t, g_t) out of the stacked history WINDOW
@@ -694,51 +727,43 @@ def _replay_segment_impl(params, vel, t0, off, W, G, cols,
     admits its L-BFGS pair — see `run_replay`).  Steps after a failed guard
     may therefore carry garbage; the caller discards them.
 
-    Under `core.store.ShardedReplay` this same body runs inside shard_map:
-    `grad_fn` is the psum-reducing variant (the schedule arrives
-    batch-sharded), `gather` all-gathers sharded history leaves one step
-    at a time, and (`axis`, `n_shards`) route the fused kernel per shard.
+    Under `core.store.PlacedReplay` it is a plain jit over sharded state:
+    `shards` routes each leaf's kernel call per device.
 
     ENCODED windows (`EncodedLeaf` leaves — the streamers' kernel decode
     mode) dequantize per step inside this scan.  On the default jnp path
     `entry_at` slice-decodes (XLA fuses the elementwise dequant); the
-    unsharded non-momentum Pallas path instead routes the encoded leaves
+    non-momentum Pallas path instead routes the encoded leaves
     straight into `kernels.dequant_update` — dequant fused with the
     subtract (v = w - w_t) and with the approx update in registers, no
     f32 window copy anywhere."""
-    use_dq = (is_encoded_window(W) and not momentum and axis is None
+    use_dq = (is_encoded_window(W) and not momentum
               and fused in ("pallas", "interpret"))
 
     def body(carry, t):
         params, vel = carry
         lr, dB, kept = sd.lr[t], sd.dB[t], sd.kept[t]
-        has = (dB > 0).astype(jnp.float32)
-        g_changed = jax.tree.map(
-            lambda x: has * x,
-            grad_fn(params, _gather(cols, sd.changed_idx[t]),
-                    sd.changed_w[t]))
+        g_changed = _changed_grad(grad_fn, params, cols, sd, t)
         if use_dq:
-            v = _dequant_sub_tree(params, W, t - off, fused)
+            v = _dequant_sub_tree(params, W, t - off, fused, shards)
         else:
-            w_t = entry_at(W, t, off, gather)
-            v = tree_sub(params, w_t)
+            v = tree_sub(params, entry_at(W, t, off))
         bv = lbfgs_hvp_stacked_pytree(dWs, dGs, v)
         guard_ok = tree_norm(bv) <= clip * tree_norm(v)
         if momentum:
-            g_t = entry_at(G, t, off, gather)
+            g_t = entry_at(G, t, off)
             g_est = _approx_math(g_t, bv, g_changed, B, dB, sign)
             ok = jnp.logical_and(tree_all_finite(g_est), guard_ok)
             new_p, new_vel = _momentum_math(params, vel, g_est, lr, mom)
         elif use_dq:
             new_p = _dequant_fused_update(params, G, t - off, bv, g_changed,
-                                          lr, B, dB, sign, fused)
+                                          lr, B, dB, sign, fused, shards)
             ok = jnp.logical_and(tree_all_finite(new_p), guard_ok)
             new_vel = vel
         else:
-            g_t = entry_at(G, t, off, gather)
+            g_t = entry_at(G, t, off)
             new_p = _flat_fused_update(params, g_t, bv, g_changed, lr, B, dB,
-                                       sign, fused, axis=axis,
-                                       n_shards=n_shards)
+                                       sign, fused, shards=shards)
             ok = jnp.logical_and(tree_all_finite(new_p), guard_ok)
             new_vel = vel
 
@@ -753,8 +778,8 @@ def _replay_segment_impl(params, vel, t0, off, W, G, cols,
 
 
 _replay_segment = partial(jax.jit, static_argnames=(
-    "grad_fn", "sign", "momentum", "fused", "span", "gather", "axis",
-    "n_shards"))(_replay_segment_impl)
+    "grad_fn", "sign", "momentum", "fused", "span", "shards"))(
+        _replay_segment_impl)
 
 
 def run_replay(
@@ -802,18 +827,17 @@ def run_replay(
                                meta.lr_at)
         plan = build_plan(cfg, sched)
         sd = to_device(sched)
+    # explicit steps donate their parameters: never the caller's
+    params = (jax.tree.map(jnp.copy, params0) if params0 is not None
+              else store.params0())
+    pin = shards = None
     if runner is not None:
-        sd = pad_schedule_batch(sd, runner.placement.data_size)
-        seg_grad_fn = make_psum_grad_fn(objective,
-                                        runner.placement.data_axis)
-        gather = runner.gather_info()
-        axis = runner.placement.data_axis
-        n_shards = runner.placement.data_size
+        params = runner.place(params)
+        pin, shards = runner.pin(params), runner.kernel_shards(params)
     in_place = batch_in_place(sched.idx, sd.idx.shape[1])
     cols = ds.device_columns()
     buffer = LbfgsBuffer(cfg.history_size, curvature_eps=cfg.curvature_eps)
 
-    params = params0 if params0 is not None else history.params_at(0)
     vel = _tree_zeros(params) if momentum else None
     Bf = jnp.float32(B)
     clip = jnp.float32(cfg.guard_norm_clip)
@@ -833,10 +857,9 @@ def run_replay(
             W, G, off = store.window(a, b)
             if runner is not None:
                 fn = runner.wrap(
-                    partial(_replay_segment_impl, grad_fn=seg_grad_fn,
+                    partial(_replay_segment_impl, grad_fn=grad_fn,
                             sign=sign, momentum=momentum, fused=fused,
-                            span=b - a, gather=gather, axis=axis,
-                            n_shards=n_shards),
+                            span=b - a, shards=shards),
                     key=("replay", b - a, sign, momentum, fused),
                     n_outputs=3)
                 return fn(p, v, jnp.int32(a), jnp.int32(off), W, G, cols,
@@ -851,7 +874,7 @@ def run_replay(
             return _host_explicit_step(
                 grad_fn, buffer, p, v, tt, store, cols, sd,
                 float(sched.kept[tt]), float(sched.dB[tt]), Bf, mom, sign,
-                momentum, in_place, stats)
+                momentum, in_place, pin, stats)
 
     t = 0
     while t < T:
@@ -947,40 +970,43 @@ def run_replay(
 
 
 @partial(jax.jit, static_argnames=("grad_fn", "sign", "momentum",
-                                   "in_place"))
-def _explicit_step(params, vel, t, w_t, g_t, cols, sd: DeviceSchedule, B,
+                                   "in_place", "pin"),
+         donate_argnames=("params",))
+def _explicit_step(params, vel, t, W, G, i, cols, sd: DeviceSchedule, B,
                    mom, *, grad_fn, sign: int, momentum: bool,
-                   in_place: bool):
+                   in_place: bool, pin=None):
     """The whole explicit step as ONE program: kept + changed gradients
-    against the store-served (w_t, g_t) history entry, pair construction
-    (with the Algorithm-4 admission inner products), and the parameter
-    update.  The host only syncs the two admission scalars — one
-    round-trip per explicit step."""
+    against the history entry (w_t, g_t) — read at index ``i`` of the
+    store's window (W, G) and decoded here, so no decoded copy of it is
+    ever stored — pair construction (with the Algorithm-4 admission inner
+    products), and the parameter update.  The host only syncs the two
+    admission scalars — one round-trip per explicit step.  `pin`
+    (`core.store.PlacedReplay.pin`) keeps the parameter-shaped outputs on
+    the parameters' placement.  `params` is donated: the new parameters
+    take its buffers (a parameter-sized tree less at an LM step's peak)."""
     k, dB, lr = sd.kept[t], sd.dB[t], sd.lr[t]
     g_kept = grad_fn(params, _read_batch(cols, sd.idx[t], in_place),
                      sd.kept_w[t])
-    has = (dB > 0).astype(jnp.float32)
-    g_changed = jax.tree.map(
-        lambda x: has * x,
-        grad_fn(params, _gather(cols, sd.changed_idx[t]), sd.changed_w[t]))
+    g_changed = _changed_grad(grad_fn, params, cols, sd, t)
     g_full, g_step = _combine_explicit(g_kept, g_changed, k, dB, B, sign)
-    dw = tree_sub(params, w_t)
-    dg = tree_sub(g_full, g_t)
+    dw = constrain(tree_sub(params, entry_at(W, i, 0)), pin)
+    dg = constrain(tree_sub(g_full, entry_at(G, i, 0)), pin)
     admit = jnp.stack([tree_vdot(dg, dw), tree_vdot(dw, dw)])
     if momentum:
         new_p, new_vel = _momentum_math(params, vel, g_step, lr, mom)
     else:
         new_p, new_vel = _sgd_math(params, g_step, lr), vel
-    return new_p, new_vel, dw, dg, admit
+    return constrain(new_p, pin), new_vel, dw, dg, admit
 
 
 def _host_explicit_step(grad_fn, buffer, params, vel, t, store, cols, sd,
-                        k, dB, Bf, mom, sign, momentum, in_place, stats):
+                        k, dB, Bf, mom, sign, momentum, in_place, pin,
+                        stats):
     """One explicit step (host-driven: it mutates the L-BFGS buffer)."""
-    w_t, g_t = store.entry(t)
+    W, G, i = store.entry_window(t)
     params, vel, dw, dg, admit = _explicit_step(
-        params, vel, t, w_t, g_t, cols, sd, Bf, mom, grad_fn=grad_fn,
-        sign=sign, momentum=momentum, in_place=in_place)
+        params, vel, t, W, G, i, cols, sd, Bf, mom, grad_fn=grad_fn,
+        sign=sign, momentum=momentum, in_place=in_place, pin=pin)
     with obs_trace.span("replay.host_sync", kind="admit"):
         curv, ss = np.asarray(admit)
     if not buffer.add_pair(dw, dg, float(curv), float(ss)):
@@ -1177,26 +1203,20 @@ def _online_explicit_math(params, vel, w_t, g_t, g_base, g_one, lr, kept, dB,
 
 def _online_segment_impl(params, vel, t0, off, W, G, cols,
                          sd: DeviceSchedule, dWs, dGs, clip, mom, *,
-                         grad_fn, sign: int, momentum: bool, span: int,
-                         gather=None):
+                         grad_fn, sign: int, momentum: bool, span: int):
     """Online approx segment: like `_replay_segment` but with the per-step
     effective batch size (paper's n-k bookkeeping), the velocity carried in
     the scan state for heavy-ball histories, and the rewrite pairs
     (w_t <- w^I_t, g_t <- g^a_t, eq. (S62)) emitted as stacked scan outputs.
     Guard verdicts are detection-only, as in `_replay_segment`.  History
-    leaves are indexed ``t - off`` (window offset for streamed stores) and
-    all-gathered per the `gather` plan when sharded across a mesh."""
+    leaves are indexed ``t - off`` (window offset for streamed stores)."""
 
     def body(carry, t):
         params, vel = carry
-        w_t = entry_at(W, t, off, gather)
-        g_t = entry_at(G, t, off, gather)
+        w_t = entry_at(W, t, off)
+        g_t = entry_at(G, t, off)
         lr, dB, kept = sd.lr[t], sd.dB[t], sd.kept[t]
-        has = (dB > 0).astype(jnp.float32)
-        g_one = jax.tree.map(
-            lambda x: has * x,
-            grad_fn(params, _gather(cols, sd.changed_idx[t]),
-                    sd.changed_w[t]))
+        g_one = _changed_grad(grad_fn, params, cols, sd, t)
         new_p, new_vel, g_new, ok = _online_approx_step(
             params, vel, w_t, g_t, dWs, dGs, g_one, lr, kept, dB, clip, mom,
             sign=sign, momentum=momentum)
@@ -1219,7 +1239,7 @@ def _online_segment_impl(params, vel, t0, off, W, G, cols,
 
 
 _online_segment = partial(jax.jit, static_argnames=(
-    "grad_fn", "sign", "momentum", "span", "gather"))(_online_segment_impl)
+    "grad_fn", "sign", "momentum", "span"))(_online_segment_impl)
 
 
 @partial(jax.jit, static_argnames=("grad_fn", "sign", "momentum",
@@ -1236,10 +1256,7 @@ def _online_explicit_step(params, vel, t, w_t, g_t, cols,
     kept, dB, lr = sd.kept[t], sd.dB[t], sd.lr[t]
     g_base = grad_fn(params, _read_batch(cols, sd.idx[t], in_place),
                      sd.kept_w[t])
-    has = (dB > 0).astype(jnp.float32)
-    g_one = jax.tree.map(
-        lambda x: has * x,
-        grad_fn(params, _gather(cols, sd.changed_idx[t]), sd.changed_w[t]))
+    g_one = _changed_grad(grad_fn, params, cols, sd, t)
     return _online_explicit_math(params, vel, w_t, g_t, g_base, g_one, lr,
                                  kept, dB, mom, sign=sign, momentum=momentum)
 
@@ -1293,7 +1310,6 @@ def run_online_request(
     sched: ReplaySchedule,
     cfg: DeltaGradConfig,
     static_dev: Optional[Tuple[jax.Array, jax.Array]] = None,
-    seg_grad_fn=None,
     commit: bool = True,
 ) -> Tuple[Any, RetrainStats]:
     """One online request — a single row or a coalesced GROUP of rows
@@ -1307,8 +1323,6 @@ def run_online_request(
     the stream state: liveness, added rows, join masks).  `static_dev` is
     the request-invariant (idx, lr) pair already on device — pass it so a
     stream uploads the (T, B [+pad]) schedule once, not per request.
-    `seg_grad_fn` (default `grad_fn`) is what scanned segments use — the
-    psum-reducing variant when the store is mesh-sharded.
 
     History rewrites are fully deferred: explicit steps hand their (w, g)
     rewrite back instead of scattering per step, segment outputs stay as
@@ -1326,13 +1340,7 @@ def run_online_request(
     plan = build_plan(cfg, sched, online=True)
     sd = to_device(sched, *(static_dev or (None, None)))
     runner = store.sharded_replay()
-    gather = None
-    if runner is not None:
-        sd = pad_schedule_batch(sd, runner.placement.data_size)
-        gather = runner.gather_info()
     in_place = batch_in_place(sched.idx, sd.idx.shape[1])
-    if seg_grad_fn is None:
-        seg_grad_fn = grad_fn
     params = store.params0()  # w_0 is never rewritten
     n_params = (sum(x.size for x in jax.tree.leaves(params))
                 if obs_trace.enabled() else 0)
@@ -1434,16 +1442,15 @@ def run_online_request(
                     if runner is not None:
                         fn = runner.wrap(
                             partial(_online_segment_impl,
-                                    grad_fn=seg_grad_fn, sign=sign,
-                                    momentum=momentum, span=b - a,
-                                    gather=gather),
+                                    grad_fn=grad_fn, sign=sign,
+                                    momentum=momentum, span=b - a),
                             key=("online", b - a, sign, momentum),
                             n_outputs=5)
                         return fn(p, v, jnp.int32(a), jnp.int32(off), Wd,
                                   Gd, cols, sd, pW, pG, clip, mom)
                     return _online_segment(
                         p, v, jnp.int32(a), jnp.int32(off), Wd, Gd, cols,
-                        sd, pW, pG, clip, mom, grad_fn=seg_grad_fn,
+                        sd, pW, pG, clip, mom, grad_fn=grad_fn,
                         sign=sign, momentum=momentum, span=b - a)
 
             while t < t2:

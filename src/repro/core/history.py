@@ -64,8 +64,10 @@ bytes (f32 params) and ``T`` recorded steps:
                                       are ~2 windows of the shard and
                                       per-HOST RAM is the encoded path
                                       (``2*T*P / ratio``) plus one window
-                                      of staged slices; the shard_map
-                                      scan all-gathers one step at a time
+                                      of staged slices; parameters,
+                                      gradients and L-BFGS pairs keep
+                                      their placements too
+                                      (`core.store.PlacedReplay`)
   disk       ``~2*L*P`` (window)      longest runs; host RAM ~0, entries
                                       spill to ``spill_dir`` .npz
                                       (``spill_dir="auto"`` → a fresh
@@ -148,12 +150,110 @@ from __future__ import annotations
 
 import os
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+# --------------------------------------------------------------------------
+# Host copies of sharded arrays
+# --------------------------------------------------------------------------
+
+
+def _index_key(index, shape) -> Tuple[Tuple[int, int], ...]:
+    """A shard's index (a tuple of slices) as (start, stop) per dim."""
+    return tuple(s.indices(d)[:2] for s, d in zip(index, shape))
+
+
+class HostShards:
+    """A host array kept as the per-device blocks of the sharded device
+    array it was fetched from.  Fetching it copies each block once and
+    assembles nothing; a mesh-placed store sends each block back to its
+    device as it is (`core.store.ShardedStreamer`), so a stream window
+    costs no host copy either.  Every other reader gets the whole array
+    from ``np.asarray``."""
+
+    __slots__ = ("shape", "dtype", "pieces")
+
+    def __init__(self, shape, dtype, pieces):
+        self.shape = tuple(shape)
+        self.dtype = np.dtype(dtype)
+        self.pieces = pieces  # {_index_key: ndarray}
+
+    @property
+    def ndim(self) -> int:
+        return len(self.shape)
+
+    @property
+    def nbytes(self) -> int:
+        return sum(p.nbytes for p in self.pieces.values())
+
+    def __array__(self, dtype=None, copy=None):
+        out = np.empty(self.shape, self.dtype)
+        for key, p in self.pieces.items():
+            out[tuple(slice(a, b) for a, b in key)] = p
+        return out if dtype is None else out.astype(dtype)
+
+    def __getitem__(self, i: int) -> "HostShards":
+        """Entry `i` of the leading axis, which no placement shards."""
+        return HostShards(self.shape[1:], self.dtype,
+                          {k[1:]: p[i] for k, p in self.pieces.items()})
+
+    def block(self, index) -> np.ndarray:
+        """The block at `index` (a tuple of slices): the stored piece when
+        the placement asking for it is the one it was fetched under."""
+        p = self.pieces.get(_index_key(index, self.shape))
+        return p if p is not None else np.asarray(self)[tuple(index)]
+
+
+_FETCH_THREADS = 8
+_fetch_pool: Optional[ThreadPoolExecutor] = None
+
+
+def to_host(tree):
+    """`jax.device_get` that keeps sharded leaves as `HostShards`: every
+    transfer is started before any is waited for, the blocks land on
+    `_FETCH_THREADS` threads, nothing is assembled, and leaves already on
+    the host pass through."""
+    global _fetch_pool
+
+    def blocks(x):
+        if isinstance(x, jax.Array) and not x.is_fully_replicated:
+            out = {}
+            for s in x.addressable_shards:
+                out.setdefault(_index_key(s.index, x.shape), s.data)
+            return out
+        return {None: x} if isinstance(x, jax.Array) else None
+
+    leaves, tdef = jax.tree.flatten(tree)
+    shards = [blocks(x) for x in leaves]
+    arrays = [d for b in shards if b is not None for d in b.values()]
+    for d in arrays:
+        d.copy_to_host_async()
+    if _fetch_pool is None:
+        _fetch_pool = ThreadPoolExecutor(max_workers=_FETCH_THREADS)
+    got = iter(_fetch_pool.map(np.asarray, arrays))
+    out = []
+    for x, b in zip(leaves, shards):
+        if b is None:
+            out.append(x)
+        elif None in b:
+            out.append(next(got))
+        else:
+            out.append(HostShards(x.shape, x.dtype,
+                                  {k: next(got) for k in b}))
+    return jax.tree.unflatten(tdef, out)
+
+
+def host_block(x, index) -> np.ndarray:
+    """The block of host array `x` at `index` (a tuple of slices)."""
+    if isinstance(x, HostShards):
+        return x.block(index)
+    return np.asarray(x)[tuple(index)]
 
 
 # --------------------------------------------------------------------------
@@ -196,32 +296,15 @@ class BF16Codec(Codec):
                             stored)
 
 
-def _exact_product_scale(scale: float) -> np.float32:
-    """Round `scale` UP to 17 significant bits.  |q| <= 127 has 7, so every
-    decoded ``q * scale`` is exact in f32 and ``q * scale + base`` rounds
-    once whether or not the compiler contracts it into a fused
-    multiply-add — which XLA decides per fusion, so without this the
-    in-scan and whole-window decodes can differ in the last bit."""
-    m, e = np.frexp(np.float64(scale))
-    return np.float32(np.ldexp(np.ceil(m * 2.0**17) / 2.0**17, e))
-
-
 class Int8Codec(Codec):
     """Symmetric per-leaf absmax int8 quantization."""
 
     name = "int8"
 
     def encode(self, tree):
-        tree = jax.device_get(tree)
-
-        def enc(x):
-            x = np.asarray(x, dtype=np.float32)
-            scale = np.max(np.abs(x)) / 127.0 if x.size else 1.0
-            scale = _exact_product_scale(scale) if scale > 0 else 1.0
-            q = np.clip(np.round(x / scale), -127, 127).astype(np.int8)
-            return {"q": q, "scale": np.float32(scale)}
-
-        return jax.tree.map(enc, tree)
+        window = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32)[None],
+                              tree)
+        return _int8_entries(_int8_encode_window(window, None, None))[0]
 
     def decode(self, stored):
         def dec(d):
@@ -240,6 +323,49 @@ class Int8Codec(Codec):
 
         return jax.tree.map(dec, stored,
                             is_leaf=lambda x: isinstance(x, dict) and "q" in x)
+
+
+@jax.jit
+def _int8_encode_window(Xs, bases, kidx):
+    """The int8 codec (of the residual against keyframe ``kidx[i]`` when
+    `bases`, a tuple of keyframe trees, is given: `DeltaCodec`; they are
+    stacked inside the program) applied to each step of stacked (L, ...)
+    leaves, on the device: {"q": (L, ...) int8, "scale": (L,) f32} per leaf.  A
+    step's scale is its absmax / 127 rounded UP to 17 significant bits (an
+    all-zero step scales by 1): |q| <= 127 has 7, so every decoded
+    ``q * scale`` is exact in f32 and ``q * scale + base`` rounds once
+    whether or not the compiler contracts it into a fused multiply-add —
+    which XLA decides per fusion, so without this the in-scan and
+    whole-window decodes could differ in the last bit.  Sharded leaves
+    reduce their absmax shard-locally."""
+
+    def enc(x, *bs):
+        r = x.astype(jnp.float32)
+        if bs:
+            r = r - jnp.stack(bs)[kidx]
+        lead = (-1,) + (1,) * (r.ndim - 1)
+        amax = jnp.max(jnp.abs(r), axis=tuple(range(1, r.ndim)),
+                       initial=0.0)
+        m, e = jnp.frexp(amax / 127.0)
+        scale = jnp.ldexp(jnp.ceil(m * 2.0**17) / 2.0**17, e)
+        scale = jnp.where(amax > 0, scale, 1.0)
+        q = jnp.clip(jnp.round(r / scale.reshape(lead)), -127, 127)
+        return {"q": q.astype(jnp.int8), "scale": scale}
+
+    if bases is None:
+        return jax.tree.map(enc, Xs)
+    return jax.tree.map(enc, Xs, *bases)
+
+
+def _int8_entries(window):
+    """A device-encoded window as per-entry stored trees on the host:
+    [{"q": (...) int8, "scale": f32} per leaf, one a step]."""
+    host = to_host(window)
+    is_enc = lambda x: isinstance(x, dict) and "q" in x
+    L = jax.tree.leaves(host)[0].shape[0]
+    return [jax.tree.map(lambda d: {"q": d["q"][i],
+                                    "scale": np.float32(d["scale"][i])},
+                         host, is_leaf=is_enc) for i in range(L)]
 
 
 class DeltaCodec(Codec):
@@ -282,9 +408,11 @@ class DeltaCodec(Codec):
         self._need_base("decode_stacked()")
 
     def make_base(self, tree):
-        """Immutable f32 host copy used as the key window's keyframe."""
-        tree = jax.device_get(tree)
-        return jax.tree.map(lambda x: np.array(x, dtype=np.float32), tree)
+        """Immutable f32 host copy used as the key window's keyframe
+        (sharded leaves stay in their per-device blocks: `HostShards`)."""
+        return jax.tree.map(
+            lambda x: x if isinstance(x, HostShards)
+            else np.array(x, dtype=np.float32), to_host(tree))
 
     def encode_delta(self, tree, base):
         tree = jax.device_get(tree)
@@ -417,6 +545,13 @@ class TrainingHistory:
         self._win_dirty = False
         self.io_read_s = 0.0  # cumulative spill IO wall time
         self.io_write_s = 0.0
+        # the mesh the path was recorded under (`core.store.PlacementPolicy`,
+        # set by `core.engine.run_training`): stores built for this history
+        # are placed on it unless told otherwise
+        self.placement = None
+        # delta codecs, device-side recording: the current key window's
+        # keyframe on the device, (kwid, base_w, base_g)
+        self._dev_base: Optional[Tuple[int, Any, Any]] = None
 
     def __len__(self) -> int:
         return self._stacked_len + len(self._params)
@@ -472,29 +607,96 @@ class TrainingHistory:
             self._grads.append(grad)
             self._stacked = None
         else:
-            enc_p, enc_g = self._encode_pair(t, params, grad)
-            self._stacked = None
-            if self.tier == "host":
-                self._params.append(enc_p)
-                self._grads.append(enc_g)
-            elif self.spill_window > 1:  # disk, one .npz per window
-                flat_p, tdef = jax.tree.flatten(enc_p)
-                self._treedef = tdef
-                self._params.append(None)
-                self._grads.append(None)
-                self._spill_buf.append((enc_p, enc_g))
-                self._flush_spill()  # no-op until a window is complete
-            else:  # disk, legacy one .npz per step
-                path = os.path.join(self.spill_dir, f"step_{t:07d}.npz")
-                flat_p, tdef = jax.tree.flatten(enc_p)
-                flat_g, _ = jax.tree.flatten(enc_g)
-                t0 = time.perf_counter()
-                np.savez(path, n_p=len(flat_p), *flat_p, *flat_g)
-                self.io_write_s += time.perf_counter() - t0
-                self._params.append(None)
-                self._grads.append(None)
-                self._treedef = tdef
-                self._disk_paths.append(path)
+            self._store_encoded(t, *self._encode_pair(t, params, grad))
+
+    def encode_window(self, Ws, Gs):
+        """Append the entries of one recorded window, stacked (L, ...) on
+        the device, in two halves.  On the offload tiers the int8 codecs
+        encode it there (`_int8_encode_window`), so only the int8 payload,
+        the per-entry scales and each key window's keyframe cross to the
+        host; this half dispatches that encode and returns what
+        `land_window` stores.  Nothing here waits for the device, so a
+        recorder can dispatch its next window before landing this one and
+        the transfer to the host overlaps that window's compute.  Other
+        codecs and tiers store the window here, entry by entry, and
+        return None."""
+        inner = self.codec.inner if self.is_delta else self.codec
+        if self.tier not in ("host", "disk") \
+                or not isinstance(inner, Int8Codec):
+            host_w, host_g = jax.device_get((Ws, Gs))
+            for i in range(jax.tree.leaves(host_w)[0].shape[0]):
+                self.append(jax.tree.map(lambda x: x[i], host_w),
+                            jax.tree.map(lambda x: x[i], host_g))
+            return None
+        t0 = len(self._params)
+        L = jax.tree.leaves(Ws)[0].shape[0]
+        bases, kidx, new_keys = None, None, []
+        if self.is_delta:
+            K = self.codec.key_interval
+            kwids = range(t0 // K, (t0 + L - 1) // K + 1)
+            pairs = []
+            for k in kwids:
+                if self._dev_base is None or self._dev_base[0] != k:
+                    i0 = k * K - t0
+                    if i0 >= 0:  # key window k starts in this window
+                        bw, bg = (jax.tree.map(lambda x: x[i0], Ws),
+                                  jax.tree.map(lambda x: x[i0], Gs))
+                        new_keys.append((k, bw, bg))
+                    else:  # its keyframe came in through append()
+                        bw, bg = jax.tree.map(jnp.asarray, self._bases[k])
+                    self._dev_base = (k, bw, bg)
+                pairs.append(self._dev_base[1:])
+            bases = (tuple(p for p, _ in pairs), tuple(g for _, g in pairs))
+            kidx = jnp.asarray([(t0 + i) // K - kwids[0] for i in range(L)],
+                               jnp.int32)
+        enc_w = _int8_encode_window(Ws, None if bases is None else bases[0],
+                                    kidx)
+        enc_g = _int8_encode_window(Gs, None if bases is None else bases[1],
+                                    kidx)
+        return t0, enc_w, enc_g, new_keys
+
+    def land_window(self, pending) -> None:
+        """The host half of `encode_window`: bring its result (and the
+        keyframes its window opened) to the host and store its entries
+        (nothing for None)."""
+        if pending is None:
+            return
+        t0, enc_w, enc_g, new_keys = pending
+        assert t0 == len(self._params), (t0, len(self._params))
+        # one fetch: every transfer of the window starts before any waits
+        keys, enc_w, enc_g = to_host(
+            ([(bw, bg) for _, bw, bg in new_keys], enc_w, enc_g))
+        for (k, _, _), (bw, bg) in zip(new_keys, keys):
+            self._bases[k] = (self.codec.make_base(bw),
+                              self.codec.make_base(bg))
+        enc_w, enc_g = _int8_entries(enc_w), _int8_entries(enc_g)
+        for i in range(len(enc_w)):
+            self._store_encoded(t0 + i, enc_w[i], enc_g[i])
+
+    def _store_encoded(self, t: int, enc_p, enc_g) -> None:
+        """Keep entry `t` in stored form on the host or disk tier."""
+        self._stacked = None
+        if self.tier == "host":
+            self._params.append(enc_p)
+            self._grads.append(enc_g)
+        elif self.spill_window > 1:  # disk, one .npz per window
+            flat_p, tdef = jax.tree.flatten(enc_p)
+            self._treedef = tdef
+            self._params.append(None)
+            self._grads.append(None)
+            self._spill_buf.append((enc_p, enc_g))
+            self._flush_spill()  # no-op until a window is complete
+        else:  # disk, legacy one .npz per step
+            path = os.path.join(self.spill_dir, f"step_{t:07d}.npz")
+            flat_p, tdef = jax.tree.flatten(enc_p)
+            flat_g, _ = jax.tree.flatten(enc_g)
+            t0 = time.perf_counter()
+            np.savez(path, n_p=len(flat_p), *flat_p, *flat_g)
+            self.io_write_s += time.perf_counter() - t0
+            self._params.append(None)
+            self._grads.append(None)
+            self._treedef = tdef
+            self._disk_paths.append(path)
 
     # -- windowed disk spill (one .npz per spill_window steps) ---------------
 
@@ -570,6 +772,7 @@ class TrainingHistory:
 
     def finalize(self, final_params) -> None:
         self.final_params = final_params
+        self._dev_base = None  # recording is over: free the device keyframe
         # drain buffered writes (one batched scatter) so the pending dict
         # never outlives the run/request that produced it
         self._merge_pending()
@@ -846,7 +1049,8 @@ class TrainingHistory:
             if tree is None:
                 continue
             for leaf in jax.tree.leaves(tree):
-                total += np.asarray(leaf).nbytes
+                total += (leaf.nbytes if isinstance(leaf, HostShards)
+                          else np.asarray(leaf).nbytes)
         return total
 
     def disk_nbytes(self) -> int:

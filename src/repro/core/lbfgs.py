@@ -293,13 +293,23 @@ def bfgs_matrix_recursive(
 # --------------------------------------------------------------------------
 
 
-@jax.jit
-def _stack_pairs(dws, dgs):
-    """Stack m (dw, dg) pytree pairs along a new leading axis in ONE
-    dispatch (the un-jitted per-leaf jnp.stack calls showed up as ~half the
-    host overhead of an online request)."""
-    return (jax.tree.map(lambda *xs: jnp.stack(xs), *dws),
-            jax.tree.map(lambda *xs: jnp.stack(xs), *dgs))
+def _push_pair(dWs, dGs, dw, dg):
+    """Append one (dw, dg) pair to stacked (k, ...) rings."""
+    push = lambda r, x: jnp.concatenate([r, x[None].astype(r.dtype)])
+    return jax.tree.map(push, dWs, dw), jax.tree.map(push, dGs, dg)
+
+
+def _shift_pair(dWs, dGs, dw, dg):
+    """Drop the oldest pair of full (m, ...) rings and append (dw, dg)."""
+    shift = lambda r, x: jnp.concatenate([r[1:], x[None].astype(r.dtype)])
+    return jax.tree.map(shift, dWs, dw), jax.tree.map(shift, dGs, dg)
+
+
+_start_ring = jax.jit(lambda dw, dg: jax.tree.map(lambda x: x[None], (dw, dg)))
+_grow_ring = jax.jit(_push_pair)
+# a full ring is rewritten in place: the pairs live once on the device, at
+# LM scale four parameter-sized buffers
+_turn_ring = jax.jit(_shift_pair, donate_argnums=(0, 1))
 
 
 class LbfgsBuffer:
@@ -309,28 +319,36 @@ class LbfgsBuffer:
     models (paper Appendix C.3): a pair enters the buffer only if
     ``<dg, dw> >= curvature_eps * <dw, dw>`` — for strongly convex objectives
     this always holds with ``curvature_eps <= mu``.
+
+    The pairs are kept ONLY stacked, (len, ...) per leaf with the newest
+    last — the form the replay programs consume — so no second copy of
+    the ring exists; a replaced ring is invalid once a pair is added to a
+    full buffer (its buffers are donated to the new one).
     """
 
     def __init__(self, capacity: int, curvature_eps: float = 0.0):
         assert capacity >= 1
         self.capacity = capacity
         self.curvature_eps = float(curvature_eps)
-        self._dws: List = []
-        self._dgs: List = []
-        self._stacked_cache = None  # invalidated on add()
+        self._ring = None  # (dWs, dGs), every leaf (len, ...)
+        self._len = 0
         self.rejected = 0
         self.admitted = 0
 
     def __len__(self) -> int:
-        return len(self._dws)
+        return self._len
+
+    def _unstack(self, i: int) -> List:
+        return [jax.tree.map(lambda x: x[k], self._ring[i])
+                for k in range(self._len)]
 
     @property
     def dws(self) -> List:
-        return list(self._dws)
+        return self._unstack(0) if self._ring is not None else []
 
     @property
     def dgs(self) -> List:
-        return list(self._dgs)
+        return self._unstack(1) if self._ring is not None else []
 
     def add(self, dw, dg) -> bool:
         """Returns True if the pair was admitted."""
@@ -344,35 +362,29 @@ class LbfgsBuffer:
         if ss <= 0.0 or curv < self.curvature_eps * ss:
             self.rejected += 1
             return False
-        self._dws.append(dw)
-        self._dgs.append(dg)
-        if len(self._dws) > self.capacity:
-            self._dws.pop(0)
-            self._dgs.pop(0)
-        self._stacked_cache = None
+        if self._ring is None:
+            self._ring = _start_ring(dw, dg)
+        elif self._len < self.capacity:
+            self._ring = _grow_ring(*self._ring, dw, dg)
+        else:
+            self._ring = _turn_ring(*self._ring, dw, dg)
+        self._len = min(self._len + 1, self.capacity)
         self.admitted += 1
         return True
 
     def hvp(self, v):
         """B v. Requires at least one admitted pair."""
-        if not self._dws:
+        if self._ring is None:
             raise ValueError("LbfgsBuffer.hvp called with no admitted pairs")
-        return lbfgs_hvp_pytree(self._dws, self._dgs, v)
+        return lbfgs_hvp_pytree(self.dws, self.dgs, v)
 
     def stacked(self):
-        """(dWs, dGs) with every leaf stacked along a new leading axis.
-
-        Cached between add() calls — approx steps between two explicit steps
-        reuse the same stacked buffers without re-dispatching the stacks.
-        """
-        if not self._dws:
+        """(dWs, dGs) with every leaf stacked along a leading axis, oldest
+        first; the same arrays until the next admitted pair."""
+        if self._ring is None:
             raise ValueError("LbfgsBuffer.stacked called with no admitted pairs")
-        if self._stacked_cache is None:
-            self._stacked_cache = _stack_pairs(tuple(self._dws),
-                                               tuple(self._dgs))
-        return self._stacked_cache
+        return self._ring
 
     def clear(self) -> None:
-        self._dws.clear()
-        self._dgs.clear()
-        self._stacked_cache = None
+        self._ring = None
+        self._len = 0

@@ -25,9 +25,8 @@ GROUP of rows (`request_group`, one replay for K requests — the
 session planner's batching primitive), SGD or momentum — through
 `core.engine.run_online_request`: approx segments execute under `lax.scan`
 against the history served by a `core.store.HistoryStore` — fully resident
-(stacked/device tiers, optionally mesh-sharded with psum-reduced
-per-example gradients) or streamed per segment window from the offload
-tiers (host/disk) — and rewrites land in batched flushes through
+(stacked/device tiers, optionally mesh-sharded) or streamed per segment
+window from the offload tiers (host/disk) — and rewrites land in batched flushes through
 `store.commit` (an O(1) pointer swap for resident storage, a codec
 write-back for streamed).  `impl="python"` selects
 `_online_request_python`, a per-step oracle driving the SAME precomputed
@@ -51,8 +50,7 @@ from repro.core.engine import (SKIP, EXPLICIT, _online_approx_step,
                                _online_explicit_math, _ring_append,
                                _scan_pred, build_plan, run_online_request)
 from repro.core.history import TrainingHistory
-from repro.core.store import (HistoryStore, PlacementPolicy,
-                              make_psum_grad_fn)
+from repro.core.store import HistoryStore, PlacementPolicy
 from repro.data.dataset import Dataset
 from repro.data.sampler import (ReplaySchedule, addition_mask_all,
                                 batch_indices_all, build_online_schedule)
@@ -145,15 +143,10 @@ class OnlineEngine:
         self._row_cap = ds.n + (_next_pow2(self.add_capacity)
                                 if self.add_capacity else 0)
         self.store: Optional[HistoryStore] = None
-        self._seg_grad_fn = None
         if self.impl == "scan":
             self.store = store if store is not None else HistoryStore.create(
                 history, placement=placement, window=cfg.stream_window,
                 decode=cfg.stream_decode)
-            runner = self.store.sharded_replay()
-            if runner is not None:
-                self._seg_grad_fn = make_psum_grad_fn(
-                    objective, runner.placement.data_axis)
             self._lr_dev = jnp.asarray(
                 [meta.lr_at(t) for t in range(meta.steps)], jnp.float32)
             self._idx_dev = None  # uploaded lazily, re-used across requests
@@ -248,7 +241,6 @@ class OnlineEngine:
                 out = run_online_request(self.grad_fn, self.store,
                                          self._cols(), sched, self.cfg,
                                          static_dev=self._static_dev(sched),
-                                         seg_grad_fn=self._seg_grad_fn,
                                          commit=False)
                 jax.block_until_ready(out[0])
         self.compile_time_s = time.perf_counter() - t0
@@ -312,8 +304,7 @@ class OnlineEngine:
                 # dies mid-stream
                 params, rstat = run_online_request(
                     self.grad_fn, self.store, self._cols(), sched, self.cfg,
-                    static_dev=self._static_dev(sched),
-                    seg_grad_fn=self._seg_grad_fn)
+                    static_dev=self._static_dev(sched))
             else:
                 params, rstat = _online_request_python(
                     self.grad_fn, self.history, self.ds, sched, self.cfg)

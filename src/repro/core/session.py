@@ -397,6 +397,7 @@ class UnlearnerSession:
             codec=c.history_codec,
             spill_dir=c.spill_dir,
             window=c.deltagrad.stream_window,
+            placement=c.placement,
         )
         self._algorithm = None
         return self._trained_params

@@ -10,14 +10,7 @@ placement/transport layer between `TrainingHistory` and the engines:
     one device pytree; with a `PlacementPolicy` each leaf is placed by
     `dist.sharding.stacked_spec_for_leaf` (time axis never sharded), so the
     cache shards across the mesh exactly like the live parameters and the
-    per-host HBM share drops by the mesh factor.  The engines' segment
-    scans then run under ``shard_map`` (built here by `ShardedReplay`):
-    the minibatch schedule is batch-sharded over the mesh's data axis,
-    per-example gradients are ``psum``-reduced with the global weight sum
-    (`make_psum_grad_fn` — bit-compatible with the single-device weighted
-    mean up to reduction order), sharded history leaves are all-gathered
-    one step at a time inside the scan body, and the fused-update kernel
-    is routed per shard over the flattened parameter vector.
+    per-host HBM share drops by the mesh factor.
 
   * ``SegmentStreamer`` — host/disk tiers.  History entries stay encoded on
     host (or spilled .npz); the replay scan is served device-resident
@@ -38,11 +31,15 @@ placement/transport layer between `TrainingHistory` and the engines:
     axes as `ResidentStore` (time axis never sharded); the worker threads
     stack and upload ONLY each mesh shard's slice of each leaf
     (`jax.make_array_from_single_device_arrays` assembles the global
-    window), the codec decodes shard-local on device, and the engines'
-    ``shard_map`` scans all-gather the decoded window one step at a time
-    exactly as the resident path does.  Device high-water is ~2 windows
-    of the SHARD; per-host RAM holds the encoded path (/codec ratio) plus
-    one window of staged slices.
+    window), the codec decodes shard-local on device.  Device high-water
+    is ~2 windows of the SHARD; per-host RAM holds the encoded path
+    (/codec ratio) plus one window of staged slices.
+
+A mesh-placed store's replays run `PlacedReplay`'s programs: parameters,
+gradients, L-BFGS pairs and windows keep their `dist.sharding.spec_for_leaf`
+placements for the whole replay, the programs are plain jits over those
+sharded arrays with XLA inserting the collectives, and ``shard_map`` runs
+only around the Pallas update kernels, which XLA does not partition.
 
 Both streamers additionally support DECODE-IN-KERNEL reads
 (``decode="kernel"``, the default for lossy codecs): windows stay ENCODED
@@ -67,6 +64,7 @@ chooses the policy.
 
 from __future__ import annotations
 
+import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from dataclasses import dataclass
@@ -77,7 +75,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.history import Int8Codec, TrainingHistory
+from repro.core.history import TrainingHistory, host_block
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
@@ -209,14 +207,12 @@ def decoded_window_nbytes(tree) -> int:
 class PlacementPolicy:
     """Describes the replay mesh; builds the live `jax.sharding.Mesh` lazily.
 
-    ``mesh_shape``/``axis_names`` feed `jax.make_mesh`; ``data_axis`` names
-    the axis per-example gradients reduce over (batch sharding).  The
-    descriptor is plain data so `UnlearnerSession.save()` can round-trip it
-    through a checkpoint and rebuild the mesh on the restoring host."""
+    ``mesh_shape``/``axis_names`` feed `jax.make_mesh`.  The descriptor is
+    plain data so `UnlearnerSession.save()` can round-trip it through a
+    checkpoint and rebuild the mesh on the restoring host."""
 
     mesh_shape: Tuple[int, ...]
     axis_names: Tuple[str, ...] = ("data", "model")
-    data_axis: str = "data"
     model_cfg: Any = None  # optional ModelConfig for the MoE spec rules
 
     def __post_init__(self):
@@ -225,11 +221,9 @@ class PlacementPolicy:
         self._mesh = None
 
     @classmethod
-    def from_mesh(cls, mesh, data_axis: str = "data",
-                  model_cfg=None) -> "PlacementPolicy":
+    def from_mesh(cls, mesh, model_cfg=None) -> "PlacementPolicy":
         pol = cls(mesh_shape=tuple(mesh.devices.shape),
-                  axis_names=tuple(mesh.axis_names), data_axis=data_axis,
-                  model_cfg=model_cfg)
+                  axis_names=tuple(mesh.axis_names), model_cfg=model_cfg)
         pol._mesh = mesh
         return pol
 
@@ -247,10 +241,17 @@ class PlacementPolicy:
         return self._mesh
 
     @property
-    def data_size(self) -> int:
-        if self.data_axis not in self.axis_names:
-            return 1
-        return self.mesh_shape[self.axis_names.index(self.data_axis)]
+    def shards_state(self) -> bool:
+        """Whether the mesh shards the model's state: a ``model`` axis of
+        size > 1, over which `dist.sharding.spec_for_leaf` splits the
+        parameters (recording then trains on the placement too)."""
+        return ("model" in self.axis_names
+                and self.mesh_shape[self.axis_names.index("model")] > 1)
+
+    def param_shardings(self, params):
+        """NamedSharding pytree of `params` on this mesh."""
+        from repro.dist.sharding import params_shardings
+        return params_shardings(self.plan(), params)
 
     def plan(self):
         from repro.dist.sharding import ShardingPlan
@@ -271,42 +272,7 @@ class PlacementPolicy:
         session save/restore pickles the policy object itself, which is
         what preserves ``model_cfg`` (the MoE spec rules)."""
         return {"mesh_shape": list(self.mesh_shape),
-                "axis_names": list(self.axis_names),
-                "data_axis": self.data_axis}
-
-
-# --------------------------------------------------------------------------
-# Data-parallel gradients: the weighted mean as a psum (shard_map bodies)
-# --------------------------------------------------------------------------
-
-
-def make_psum_grad_fn(objective, axis: str):
-    """`Objective.make_grad_fn` semantics under batch sharding.
-
-    Each mesh member evaluates the weighted-SUM gradient over its rows; the
-    sum and the weight total ``psum`` over `axis`, and the l2 term is added
-    once after the reduction — algebraically identical to the single-device
-    weighted mean ``(sum_i w_i grad_i) / max(sum_i w_i, 1) + l2*params``,
-    differing only in float reduction order.  Cached per (objective, axis)
-    so repeated segment calls reuse the traced closure."""
-    cache = getattr(objective, "_psum_grad_fns", None)
-    if cache is None:
-        cache = objective._psum_grad_fns = {}
-    if axis not in cache:
-        gsum = jax.grad(
-            lambda p, b, w: jnp.sum(objective.per_example_loss(p, b) * w))
-
-        def grad_fn(params, batch, weights):
-            g = gsum(params, batch, weights)
-            den = jnp.maximum(jax.lax.psum(jnp.sum(weights), axis), 1.0)
-            g = jax.tree.map(lambda x: jax.lax.psum(x, axis) / den, g)
-            if objective.l2:
-                g = jax.tree.map(lambda x, p: x + objective.l2 * p, g,
-                                 params)
-            return g
-
-        cache[axis] = grad_fn
-    return cache[axis]
+                "axis_names": list(self.axis_names)}
 
 
 # --------------------------------------------------------------------------
@@ -333,7 +299,12 @@ class HistoryStore:
         window to f32 on arrival (the pre-encoded-window behaviour);
         "kernel" keeps windows ENCODED on device and the scan dequantizes
         per step in registers (HBM high-water drops by the codec ratio);
-        "auto" → "kernel" for every non-f32 codec."""
+        "auto" → "kernel" for every non-f32 codec.
+
+        Without a `placement`, the one the path was recorded under
+        (`TrainingHistory.placement`) places the store."""
+        if placement is None:
+            placement = history.placement
         if history.tier in ("host", "disk"):
             if placement is not None \
                     and int(np.prod(placement.mesh_shape)) > 1:
@@ -361,8 +332,15 @@ class HistoryStore:
         index ``W[t - off]``."""
         raise NotImplementedError
 
-    def entry(self, t: int):
+    def entry_window(self, t: int):
+        """(W, G, i): a device window holding entry `t` at index ``i``
+        (encoded leaves stay encoded), for programs that read the entry
+        themselves instead of a decoded copy."""
         raise NotImplementedError
+
+    def entry(self, t: int):
+        """(w_t, g_t) decoded, as ONE jitted program."""
+        return _entry_slices(*self.entry_window(t))
 
     def params0(self):
         return self.entry(0)[0]
@@ -373,8 +351,8 @@ class HistoryStore:
         finalize `final_params` into the history."""
         raise NotImplementedError
 
-    def sharded_replay(self) -> Optional["ShardedReplay"]:
-        """The shard_map program builder when this store is mesh-placed."""
+    def sharded_replay(self) -> Optional["PlacedReplay"]:
+        """The store's `PlacedReplay` when it is mesh-placed."""
         return None
 
     def hbm_high_water(self) -> int:
@@ -430,20 +408,13 @@ class ResidentStore(HistoryStore):
         self.history = history
         self.placement = placement
         W, G = history.stacked_view()
-        self._specs = None
-        self._flat_specs_w: Optional[List[Any]] = None
         if placement is not None:
             from repro.dist.sharding import history_shardings
             plan = placement.plan()
-            shard_w = history_shardings(plan, W)
-            shard_g = history_shardings(plan, G)
-            W = jax.tree.map(jax.device_put, W, shard_w)
-            G = jax.tree.map(jax.device_put, G, shard_g)
-            self._specs = (jax.tree.map(lambda s: s.spec, shard_w),
-                           jax.tree.map(lambda s: s.spec, shard_g))
-            self._flat_specs_w = [s.spec for s in jax.tree.leaves(shard_w)]
+            W = jax.tree.map(jax.device_put, W, history_shardings(plan, W))
+            G = jax.tree.map(jax.device_put, G, history_shardings(plan, G))
         self.W, self.G = W, G
-        self._sharded: Optional["ShardedReplay"] = None
+        self._sharded: Optional["PlacedReplay"] = None
         self._hbm = self._per_device_bytes()
 
     def _per_device_bytes(self) -> int:
@@ -451,23 +422,14 @@ class ResidentStore(HistoryStore):
         supposed to shrink (nbytes / mesh factor for sharded leaves)."""
         return tree_device_nbytes((self.W, self.G))
 
-    @property
-    def specs(self):
-        """Per-leaf (W, G) PartitionSpec trees when placed on a mesh."""
-        return self._specs
-
-    @property
-    def window_specs(self):
-        return self._specs  # resident windows are always decoded leaves
-
     def span_end(self, t: int, t2: int) -> int:
         return t2  # the whole path is resident; never split a segment
 
     def window(self, a: int, b: int):
         return self.W, self.G, 0
 
-    def entry(self, t: int):
-        return _entry_slices(self.W, self.G, t)
+    def entry_window(self, t: int):
+        return self.W, self.G, t
 
     def commit(self, regions, final_params) -> None:
         for t0, kinds, pw, pg in regions:
@@ -480,11 +442,11 @@ class ResidentStore(HistoryStore):
         self.history.replace_from_stacked(self.W, self.G,
                                           final_params=final_params)
 
-    def sharded_replay(self) -> Optional["ShardedReplay"]:
+    def sharded_replay(self) -> Optional["PlacedReplay"]:
         if self.placement is None:
             return None
         if self._sharded is None:
-            self._sharded = ShardedReplay(self)
+            self._sharded = PlacedReplay(self)
         return self._sharded
 
     def hbm_high_water(self) -> int:
@@ -558,7 +520,6 @@ class SegmentStreamer(HistoryStore):
         # SINGLE window's staged bytes (depth k stages up to k windows
         # concurrently); guarded by a lock because staging runs on pool
         # threads once the depth exceeds 1
-        import threading
         self._meter_lock = threading.Lock()
         self.host_stage_bytes = 0
         self.host_stage_high = 0
@@ -633,7 +594,8 @@ class SegmentStreamer(HistoryStore):
                 kidx = base_w = base_g = None
             Wh = self._wrap_encoded(Wh, base_w, kidx)
             Gh = self._wrap_encoded(Gh, base_g, kidx)
-        self._note_stage_bytes(tree_nbytes((Wh, Gh)))
+        nbytes = tree_nbytes((Wh, Gh))
+        self._note_stage_bytes(nbytes, nbytes)
         return jax.device_put((Wh, Gh))
 
     def _stack_host(self, wid: int):
@@ -649,17 +611,21 @@ class SegmentStreamer(HistoryStore):
             else 0.5 * self._stack_ema + 0.5 * dt
         return staged
 
-    def _note_stage_bytes(self, nbytes: int) -> None:
+    def _note_stage_bytes(self, nbytes: int, per_chip: float) -> None:
+        """`nbytes` staged on the host for one window, `per_chip` of them
+        put on each device (``store.staged_bytes``)."""
         with self._meter_lock:
             self.host_stage_bytes = int(nbytes)
             self.host_stage_high = max(self.host_stage_high, int(nbytes))
+        obs_metrics.get_registry().counter(
+            "store.staged_bytes", unit="B", owner="core.store").inc(per_chip)
 
     def _decode(self, staged):
         """Read path: fetch mode decodes the whole window to f32 on
         arrival; kernel mode hands the ENCODED window straight to the
         scan (per-step dequant in `entry_at` / the Pallas kernels).
         Both modes share one decode expression whose product the int8
-        codec keeps exact (`core.history._exact_product_scale`), which is
+        codec keeps exact (`core.history._int8_encode_window`), which is
         what makes fetch-mode and kernel-mode replays bitwise identical."""
         if self.decode_mode == "kernel":
             return staged
@@ -702,31 +668,48 @@ class SegmentStreamer(HistoryStore):
         reg.counter("store.windows_fetched", owner="core.store").inc()
         return W, G
 
-    def _evict_before(self, wid: int) -> None:
-        for old in [w for w in self._buf if w < wid]:
+    def _evict_outside(self, wid: int) -> None:
+        """Drop every buffered window but `wid`, and the prefetches behind
+        it: a window left from an earlier pass over the path is not read
+        before it is fetched again, and would only hold device memory."""
+        for old in [w for w in self._buf if w != wid]:
             W, G = self._buf.pop(old)
             self._hbm_now -= tree_device_nbytes(W) + tree_device_nbytes(G)
         for old in [w for w in self._inflight if w < wid]:
             self._inflight.pop(old)
 
-    def _prefetch(self, wid: int) -> None:
+    def _prefetch(self, wid: int, cur: int) -> None:
         if (self._pool is None or wid in self._buf or wid in self._inflight
-                or wid * self.window_len >= self.T):
+                or wid * self.window_len >= self.T
+                or not self._stages_beside(cur, wid)):
             return
         self._inflight[wid] = self._pool.submit(self._stack_host, wid)
+
+    def _stages_beside(self, cur: int, wid: int) -> bool:
+        """Whether window `wid` may stage while window `cur` is in use."""
+        return True
 
     def _choose_depth(self) -> int:
         """Prefetch depth for the next windows: 1 while the host keeps up,
         ceil(stack / scan) once stacking is MEASURABLY slower than the
         scan that consumes a window (ROADMAP adaptive-depth item).  The
         1 ms floor keeps microsecond-scale timing noise from buying extra
-        device-resident windows that cannot possibly pay for themselves."""
+        device-resident windows that cannot possibly pay for themselves.
+        Windows in flight hold at most a tenth of a device's memory
+        (`_depth_cap`): at LM scale one window is gigabytes a device."""
         if (self._scan_ema <= 0.0 or self._stack_ema <= 1e-3
                 or self._stack_ema <= self._scan_ema):
             return 1
-        depth = min(self.max_prefetch,
+        depth = min(self.max_prefetch, self._depth_cap(),
                     int(np.ceil(self._stack_ema / self._scan_ema)))
         return max(1, depth)
+
+    def _depth_cap(self) -> int:
+        stats = jax.local_devices()[0].memory_stats() or {}
+        limit = stats.get("bytes_limit", 0)
+        if not limit or not self._enc_bytes:
+            return self.max_prefetch
+        return max(1, int(0.1 * limit // self._enc_bytes))
 
     def window(self, a: int, b: int):
         now = time.perf_counter()
@@ -738,17 +721,32 @@ class SegmentStreamer(HistoryStore):
                 else 0.5 * self._scan_ema + 0.5 * dt
         wid = self._wid(a)
         assert b <= self._bounds(wid)[1], (a, b, self.window_len)
-        with obs_trace.span("store.window", wid=wid,
-                            hit=wid in self._buf or wid in self._inflight):
-            self._evict_before(wid)
-            W, G = self._fetch(wid)
+        W, G = self._window_at(wid)
+        self._last_return_ts = time.perf_counter()
+        return W, G, wid * self.window_len
+
+    def entry_window(self, t: int):
+        """Explicit steps read their entry out of the OWNING window, fetched
+        (and the next ones prefetched) as for a scanned segment: the
+        sharded and single-device, streamed and resident explicit-step
+        programs then decode alike, which keeps their parity exact."""
+        wid = self._wid(t)
+        W, G = self._window_at(wid)
+        return W, G, t - wid * self.window_len
+
+    def _window_at(self, wid: int):
+        if wid not in self._buf:
+            with obs_trace.span("store.window", wid=wid,
+                                hit=wid in self._inflight):
+                self._evict_outside(wid)
+                self._fetch(wid)
         # double buffering (depth 1), or deeper when the host is the
-        # bottleneck: ship windows s+1..s+k while the scan for s computes
+        # bottleneck: ship windows s+1..s+k while the device reads s
         depth = self._choose_depth()
         self.prefetch_depth = depth
         self.depth_used = max(self.depth_used, depth)
         for ahead in range(1, depth + 1):
-            self._prefetch(wid + ahead)
+            self._prefetch(wid + ahead, wid)
         # in-flight staged copies are device-resident too — that is the
         # buffering cost the high-water must report (at ENCODED size:
         # decode happens on the consuming fetch)
@@ -758,15 +756,7 @@ class SegmentStreamer(HistoryStore):
         obs_metrics.get_registry().gauge(
             "store.hbm_high_water_bytes", unit="B",
             owner="core.store").set_max(self._hbm_high)
-        self._last_return_ts = time.perf_counter()
-        return W, G, wid * self.window_len
-
-    def entry(self, t: int):
-        wid = self._wid(t)
-        if wid in self._buf:
-            W, G = self._buf[wid]
-            return _entry_slices(W, G, t - wid * self.window_len)
-        return self.history.entry(t)
+        return self._buf[wid]
 
     # -- online rewrite commit ----------------------------------------------
 
@@ -820,9 +810,8 @@ class ShardedStreamer(SegmentStreamer):
     window without any device ever holding a whole leaf.  The codec
     decodes shard-local on device (`out_shardings` pins the decoded
     window to the same placement), and `sharded_replay()` hands the
-    engines the same `ShardedReplay` program builder the resident path
-    uses — the shard_map scan body all-gathers the decoded window one
-    step at a time, so `run_replay` / `run_online_request` run unchanged.
+    engines a `PlacedReplay` as the resident path does, so `run_replay`
+    and `run_online_request` run unchanged.
 
     Online rewrites commit exactly like `SegmentStreamer`: the request's
     (replicated) rewrite chunks land back in the owning history entries
@@ -839,7 +828,7 @@ class ShardedStreamer(SegmentStreamer):
                  placement: PlacementPolicy, window: int = 0,
                  prefetch: bool = True, max_prefetch: int = 4,
                  stage_threads: Optional[int] = None,
-                 stage_workers: int = 4, decode: str = "auto"):
+                 stage_workers: int = 8, decode: str = "auto"):
         assert placement is not None
         need = int(np.prod(np.asarray(placement.mesh_shape, dtype=np.int64)))
         have = jax.device_count()
@@ -859,54 +848,26 @@ class ShardedStreamer(SegmentStreamer):
 
         plan = placement.plan()
         from repro.dist.sharding import stacked_entry_shardings
-        w0, g0 = history.entry(0)  # per-step template (paths + shapes)
+        # per-step template (paths + shapes): the stored entry's payloads,
+        # read on the host without decoding it onto a device
+        payload = lambda x: x["q"] if _is_enc_leaf(x) else x
+        w0, g0 = (jax.tree.map(payload, e, is_leaf=_is_enc_leaf)
+                  for e in history.encoded_entry(0))
         self._shard_w = stacked_entry_shardings(plan, w0)
         self._shard_g = stacked_entry_shardings(plan, g0)
-        self._specs = (jax.tree.map(lambda s: s.spec, self._shard_w),
-                       jax.tree.map(lambda s: s.spec, self._shard_g))
-        self._flat_specs_w = [s.spec
-                              for s in jax.tree.leaves(self._shard_w)]
         self._rep_sharding = NamedSharding(placement.mesh, PartitionSpec())
-        if self.decode_mode == "kernel":
-            # the windows the engines see are ENCODED — build the matching
-            # EncodedLeaf spec trees for shard_map (q/base shard like the
-            # decoded leaf, time axis and keyframe axis never sharded;
-            # scale/kidx replicate)
-            codec = history.codec
-            inner = codec.inner if history.is_delta else codec
-            has_scale = isinstance(inner, Int8Codec)
-            has_base = history.is_delta
-
-            def espec(s):
-                return EncodedLeaf(
-                    q=s, scale=PartitionSpec() if has_scale else None,
-                    base=s if has_base else None,
-                    kidx=PartitionSpec() if has_base else None)
-
-            self._window_specs = (jax.tree.map(espec, self._specs[0]),
-                                  jax.tree.map(espec, self._specs[1]))
-        else:
-            self._window_specs = self._specs
         self._stage_pool = ThreadPoolExecutor(
-            max_workers=max(1, min(int(stage_workers), need)))
+            max_workers=max(1, int(stage_workers)))
         self._decode_fn = None
-        self._sharded: Optional["ShardedReplay"] = None
-        # staged keyframe bases per window: bases are IMMUTABLE (online
-        # rewrites re-encode against the same keyframe), so repeated
-        # replays off one store ship each window's base shards once
-        self._base_cache: Dict[int, Tuple[Any, Any, Any, int]] = {}
-
-    @property
-    def specs(self):
-        """Per-leaf (W, G) PartitionSpec trees (same contract as a
-        mesh-placed `ResidentStore`)."""
-        return self._specs
-
-    @property
-    def window_specs(self):
-        """Spec trees matching what `window()` RETURNS — EncodedLeaf spec
-        trees in kernel decode mode, the decoded-leaf specs otherwise."""
-        return self._window_specs
+        self._sharded: Optional["PlacedReplay"] = None
+        # staged keyframe bases, keyed by the key windows a stream window
+        # touches: bases are IMMUTABLE (online rewrites re-encode against
+        # the same keyframe), so windows of one key window share one
+        # staging.  Only the current one is kept, and no window of other
+        # key windows stages beside it (`_stages_beside`): at LM scale a
+        # keyframe pair is two parameter-sized f32 trees per device
+        self._base_cache: Dict[Tuple[int, ...], Tuple[Any, Any]] = {}
+        self._base_lock = threading.Lock()
 
     # -- per-shard staging ---------------------------------------------------
 
@@ -917,19 +878,24 @@ class ShardedStreamer(SegmentStreamer):
         stage pool so shards stack/ship concurrently; each shard appends
         its slice bytes to `meter` (list.append is atomic, and the meter
         is local to ONE window's stage, so concurrent windows under
-        adaptive depth never clobber each other's sums)."""
+        adaptive depth never clobber each other's sums).  Returns a
+        function that waits for the uploads and assembles the global
+        leaf, so a window starts every leaf before it waits for one."""
         gshape = (len(column),) + tuple(np.shape(column[0]))
         idx_map = sharding.addressable_devices_indices_map(gshape)
 
         def one_shard(dev, index):
             per_entry = index[1:]  # the time axis is never sharded
-            buf = np.stack([np.asarray(e)[per_entry] for e in column])
+            blocks = [host_block(e, per_entry) for e in column]
+            # one entry goes as it is stored: a `HostShards` block is
+            # already this device's slice, so nothing is copied
+            buf = blocks[0][None] if len(blocks) == 1 else np.stack(blocks)
             meter.append(buf.nbytes)
             return jax.device_put(buf, dev)
 
         futs = [self._stage_pool.submit(one_shard, d, ix)
                 for d, ix in idx_map.items()]
-        return jax.make_array_from_single_device_arrays(
+        return lambda: jax.make_array_from_single_device_arrays(
             gshape, sharding, [f.result() for f in futs])
 
     def _stage_tree(self, entries, shardings, meter: List[int],
@@ -946,11 +912,11 @@ class ShardedStreamer(SegmentStreamer):
         if base_flat is None:
             base_flat = [None] * len(flat0)
         encoded = self.history.codec.name != "f32"
-        out = []
+        staged = []
         for proto, sh, col, bs in zip(flat0, jax.tree.leaves(shardings),
                                       cols, base_flat):
             if not encoded:
-                out.append(self._stage_leaf(sh, col, meter))
+                staged.append((self._stage_leaf(sh, col, meter), None, bs))
                 continue
             if _is_enc_leaf(proto):
                 q = self._stage_leaf(sh, [c["q"] for c in col], meter)
@@ -960,32 +926,57 @@ class ShardedStreamer(SegmentStreamer):
             else:  # bf16 residual — no per-step scale
                 q = self._stage_leaf(sh, col, meter)
                 scale = None
-            out.append(EncodedLeaf(
-                q=q, scale=scale, base=bs,
-                kidx=None if bs is None else kidx_dev))
-        return jax.tree.unflatten(tdef, out)
+            staged.append((q, scale, bs))
+        if not encoded:
+            return jax.tree.unflatten(tdef, [q() for q, _, _ in staged])
+        return jax.tree.unflatten(tdef, [
+            EncodedLeaf(q=q(), scale=scale, base=bs,
+                        kidx=None if bs is None else kidx_dev)
+            for q, scale, bs in staged])
+
+    def _key_windows(self, wid: int) -> Tuple[int, ...]:
+        a, b = self._bounds(wid)
+        K = self.history.key_interval
+        return tuple(range(a // K, (b - 1) // K + 1))
+
+    def _stages_beside(self, cur: int, wid: int) -> bool:
+        """A window of other key windows brings its own keyframe pair; it
+        stages once the current window is dropped, so that one pair is on
+        the devices at a time."""
+        return (not self.history.is_delta
+                or self._key_windows(wid) == self._key_windows(cur))
 
     def _staged_bases(self, wid: int, a: int, b: int):
         """(kidx_dev, flat base_w, flat base_g, new_bytes) for window
-        `wid`, per-shard staged and cached: the keyframes are immutable,
-        so every later fetch of the same window (other replays on this
-        store, adaptive-prefetch restages) reuses the device shards.
-        `new_bytes` is 0 on a hit so the window meter only counts the
-        first staging."""
-        hit = self._base_cache.get(wid)
-        if hit is not None:
-            return hit
-        kidx, base_w, base_g = self._window_bases(a, b)
+        `wid`: the keyframes of the key windows it touches, per-shard
+        staged, or reused from the cache when a window of the same key
+        windows staged them.  `new_bytes` (host bytes staged) is 0 on a
+        hit, so the meters count each staging once."""
+        K = self.history.key_interval
+        kwids = self._key_windows(wid)
+        kidx_dev = jax.device_put(
+            np.asarray([t // K - kwids[0] for t in range(a, b)], np.int32),
+            self._rep_sharding)
+        with self._base_lock:
+            hit = self._base_cache.get(kwids)
+            if hit is not None:
+                return (kidx_dev, *hit, 0)
+            self._base_cache.clear()  # the old pair goes before the new
+        pairs = [self.history.base_entry(k) for k in kwids]
         meter: List[int] = []
-        kidx_dev = jax.device_put(np.asarray(kidx, np.int32),
-                                  self._rep_sharding)
-        bw = [self._stage_leaf(sh, list(bs), meter)
-              for bs, sh in zip(jax.tree.leaves(base_w),
-                                jax.tree.leaves(self._shard_w))]
-        bg = [self._stage_leaf(sh, list(bs), meter)
-              for bs, sh in zip(jax.tree.leaves(base_g),
-                                jax.tree.leaves(self._shard_g))]
-        self._base_cache[wid] = (kidx_dev, bw, bg, 0)
+
+        def stage(trees, shardings):
+            return [self._stage_leaf(sh, list(col), meter)
+                    for col, sh in zip(zip(*(jax.tree.leaves(x)
+                                             for x in trees)),
+                                       jax.tree.leaves(shardings))]
+
+        bw, bg = stage([p for p, _ in pairs], self._shard_w), \
+            stage([g for _, g in pairs], self._shard_g)
+        bw, bg = [f() for f in bw], [f() for f in bg]
+
+        with self._base_lock:
+            self._base_cache[kwids] = (bw, bg)
         return kidx_dev, bw, bg, sum(meter)
 
     def _stage_window(self, wid: int):
@@ -1007,7 +998,10 @@ class ShardedStreamer(SegmentStreamer):
                                    bw, kidx_dev),
                   self._stage_tree(enc_g, self._shard_g, meter,
                                    bg, kidx_dev))
-        self._note_stage_bytes(sum(meter))
+        per_chip = tree_device_nbytes(staged)
+        if self.history.is_delta and not base_bytes:  # reused keyframes
+            per_chip -= tree_device_nbytes((bw, bg))
+        self._note_stage_bytes(sum(meter), per_chip)
         return staged
 
     def _decode(self, staged):
@@ -1029,141 +1023,109 @@ class ShardedStreamer(SegmentStreamer):
                 fn, out_shardings=(self._shard_w, self._shard_g))
         return self._decode_fn(*staged)
 
-    def entry(self, t: int):
-        """Explicit steps read per-step slices of the OWNING window, kept
-        sharded exactly like the resident path's entries — fetching the
-        window on demand keeps the sharded-streamed and sharded-resident
-        explicit-step programs (and so their float reduction orders)
-        identical, which is what makes mesh streamed-vs-resident parity
-        exact."""
-        wid = self._wid(t)
-        if wid not in self._buf:
-            self._evict_before(wid)
-            self._fetch(wid)
-        W, G = self._buf[wid]
-        return _entry_slices(W, G, t - wid * self.window_len)
-
-    def sharded_replay(self) -> Optional["ShardedReplay"]:
+    def sharded_replay(self) -> Optional["PlacedReplay"]:
         if self._sharded is None:
-            self._sharded = ShardedReplay(self)
+            self._sharded = PlacedReplay(self)
         return self._sharded
 
 
 # --------------------------------------------------------------------------
-# Sharded replay: shard_map construction for the engines' segment scans
+# Sharded replay: the segment programs of a mesh-placed store
 # --------------------------------------------------------------------------
 
 
-class ShardedReplay:
-    """Builds (and caches) the shard_map-wrapped segment programs for a
-    mesh-placed store (`ResidentStore` or `ShardedStreamer`).
+class PlacedReplay:
+    """Segment programs for a store placed on a mesh.
 
-    The engines hand their segment *impl* functions (plain, un-jitted,
-    with every static argument already bound) to `wrap`; the minibatch
-    schedule arrives batch-sharded over the data axis, parameters and
-    L-BFGS pairs replicate, and history leaves keep their storage
-    placement — sharded leaves are all-gathered ONE STEP at a time inside
-    the scan body (`gather_info`), so no device ever materializes the
-    whole stacked path (for a streamed store, not even a whole window).
-    The same per-leaf gather plan serves full-path and windowed sources:
-    a window is just a shorter, offset time axis, and the time axis is
-    never sharded."""
+    Parameters, gradients, the L-BFGS pairs and the history windows all
+    keep their `dist.sharding.spec_for_leaf` placements for the whole
+    replay — no device holds a whole parameter-sized tree.  The engines'
+    programs are plain jits over those `NamedSharding`-placed arrays, so
+    XLA partitions the model's forward and backward and inserts the
+    collectives itself; inner products over sharded leaves reduce
+    shard-locally and all-reduce scalars.  Only the Pallas update kernels,
+    which XLA does not partition, run under ``shard_map``, one call per
+    leaf on each device's block (`kernel_shards`).  Every program's
+    parameter outputs are pinned to the parameter placement (`pin`)."""
 
     def __init__(self, store: HistoryStore):
-        assert store.placement is not None and store.specs is not None
+        assert store.placement is not None
         self.store = store
         self._cache: Dict[Any, Any] = {}
+        self._sh: Dict[Any, Any] = {}
 
     @property
     def placement(self) -> PlacementPolicy:
         return self.store.placement
 
-    def gather_info(self) -> Tuple[Tuple[Tuple[int, str], ...], ...]:
-        """Per-leaf ((dim, axis_name), ...) all-gather plan for one history
-        ENTRY (the per-step leaf, after the time axis is sliced away),
-        aligned with ``jax.tree.leaves(W)``; () means replicated."""
-        out = []
-        for spec in self.store._flat_specs_w:
-            gathers = []
-            for dim, ax in enumerate(tuple(spec)[1:]):  # drop time axis
-                if ax is None:
-                    continue
-                for name in ((ax,) if isinstance(ax, str) else tuple(ax)):
-                    gathers.append((dim, name))
-            out.append(tuple(gathers))
-        return tuple(out)
+    def _shardings(self, params):
+        tdef = jax.tree.structure(params)
+        if tdef not in self._sh:
+            sh = self.placement.param_shardings(params)
+            self._sh[tdef] = (sh, tuple(jax.tree.leaves(sh)))
+        return self._sh[tdef]
 
-    def _schedule_specs(self):
-        from jax.sharding import PartitionSpec as P
+    def place(self, params):
+        """`params` on their placements (a no-op when already there)."""
+        return jax.device_put(params, self._shardings(params)[0])
 
-        from repro.core.engine import DeviceSchedule
-        d = self.placement.data_axis
-        return DeviceSchedule(idx=P(None, d), kept_w=P(None, d),
-                              changed_idx=P(None, d), changed_w=P(None, d),
-                              dB=P(), kept=P(), lr=P())
+    def pin(self, params) -> Tuple[Any, ...]:
+        """Flat NamedShardings of the parameter leaves: a static argument
+        of the engines' jitted steps, which constrain their parameter-
+        shaped outputs to it."""
+        return self._shardings(params)[1]
+
+    def kernel_shards(self, params):
+        """(mesh, flat PartitionSpecs) of the parameter leaves: a static
+        argument that routes each leaf's Pallas kernel call per shard."""
+        return (self.placement.mesh,
+                tuple(s.spec for s in self._shardings(params)[1]))
 
     def wrap(self, impl_fn, key, n_outputs: int):
-        """shard_map + jit for ``impl_fn(params, vel, t0, off, W, G, cols,
-        sd, *rest)`` with `n_outputs` replicated outputs; cached by `key`
-        (span/sign/momentum/... — everything that changes the program)."""
+        """jit for ``impl_fn(params, ...)``, its first output (the
+        parameters) pinned to their placement; cached by `key`."""
         if key in self._cache:
             return self._cache[key]
-        from jax.sharding import PartitionSpec as P
 
-        specs_w, specs_g = self.store.window_specs
-        rep = P()
-        lead = (rep, rep, rep, rep, specs_w, specs_g, rep,
-                self._schedule_specs())
-        out_specs = (rep,) * n_outputs if n_outputs > 1 else rep
-        mesh = self.placement.mesh
+        def call(params, *args):
+            out = impl_fn(params, *args)
+            return (constrain(out[0], self.pin(params)),) + tuple(out[1:])
 
-        def call(*args):
-            in_specs = lead + (rep,) * (len(args) - len(lead))
-            return jax.shard_map(impl_fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)(*args)
-
-        jitted = jax.jit(call)
-        self._cache[key] = jitted
-        return jitted
+        self._cache[key] = jax.jit(call)
+        return self._cache[key]
 
 
-def entry_at(W, t, off, gather=None):
-    """Slice one step out of stacked history leaves, all-gathering sharded
-    leaves per the ShardedReplay gather plan (no-op when gather is None).
+def constrain(tree, pin):
+    """`tree` with each leaf constrained to the matching sharding of the
+    flat `pin` (`PlacedReplay.pin`); no-op when `pin` is None."""
+    if pin is None:
+        return tree
+    leaves, tdef = jax.tree.flatten(tree)
+    return jax.tree.unflatten(tdef, [
+        jax.lax.with_sharding_constraint(x, s) for x, s in zip(leaves, pin)])
 
-    Encoded windows (`EncodedLeaf` leaves) dequantize the SLICE — shard-
-    local, before the gather — so sharded kernel-mode replay ships the
-    same f32 step the resident path would, while the window itself stays
-    encoded in HBM.  One EncodedLeaf flattens to one decoded leaf, so the
-    per-leaf gather plans line up unchanged."""
+
+def per_shard(fn, shards, i: int, scalars, arrays):
+    """``fn(*scalars, *arrays)`` for parameter leaf `i` (the `arrays` each
+    in the leaf's shape): a plain call, or, with `shards`
+    (`PlacedReplay.kernel_shards`), one call per device on its block of
+    every array, the scalars replicated.  Exact for the elementwise update
+    kernels."""
+    if shards is None:
+        return fn(*scalars, *arrays)
+    from jax.sharding import PartitionSpec as P
+
+    mesh, specs = shards
+    in_specs = (P(),) * len(scalars) + (specs[i],) * len(arrays)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=specs[i], check_vma=False)(*scalars,
+                                                              *arrays)
+
+
+def entry_at(W, t, off):
+    """Slice one step out of stacked history leaves.  Encoded windows
+    (`EncodedLeaf` leaves) dequantize the SLICE, so the window itself stays
+    encoded in HBM."""
     leaves, tdef = jax.tree.flatten(W, is_leaf=_is_window_leaf)
-    if gather is None:
-        return jax.tree.unflatten(
-            tdef, [_decode_leaf_slice(x, t - off) for x in leaves])
-    out = []
-    for leaf, plan in zip(leaves, gather):
-        x = _decode_leaf_slice(leaf, t - off)
-        for dim, ax in plan:
-            x = jax.lax.all_gather(x, ax, axis=dim, tiled=True)
-        out.append(x)
-    return jax.tree.unflatten(tdef, out)
-
-
-def pad_schedule_batch(sched_dev, multiple: int):
-    """Pad the device schedule's batch-shaped dims (axis 1) to a multiple of
-    the data-axis size with weight-0 rows, so batch sharding divides evenly.
-    Zero-weight rows gather row 0 and contribute nothing to any gradient."""
-    if multiple <= 1:
-        return sched_dev
-
-    def pad(x, fill=0):
-        b = x.shape[1]
-        want = -(-b // multiple) * multiple
-        if want == b:
-            return x
-        return jnp.pad(x, ((0, 0), (0, want - b)), constant_values=fill)
-
-    return sched_dev._replace(
-        idx=pad(sched_dev.idx), kept_w=pad(sched_dev.kept_w),
-        changed_idx=pad(sched_dev.changed_idx),
-        changed_w=pad(sched_dev.changed_w))
+    return jax.tree.unflatten(
+        tdef, [_decode_leaf_slice(x, t - off) for x in leaves])

@@ -13,8 +13,8 @@ Megatron-style rules driven by leaf PATH + SHAPE only (no per-model tables):
     padding a 140-dim head projection 16 ways wastes >10% of the shard);
   * norms / 1-D leaves replicate on model and FSDP-shard on data when
     divisible;
-  * embeddings: vocab-sharded on data only (the lm_head matmul wants d_model
-    contiguous);
+  * embeddings: vocab-sharded on data (the lm_head matmul wants d_model
+    contiguous), on model where data does not divide the vocabulary;
   * MoE routed experts (leaves shaped (E, d_in, d_out) under ``mlp``):
     expert-parallel on the model axis when E divides it, else
     tensor-parallel on (d_in, d_out) with the expert axis replicated.
@@ -54,10 +54,9 @@ def make_plan(mesh, cfg=None) -> ShardingPlan:
 
 def make_mesh(shape: Tuple[int, ...], axis_names: Tuple[str, ...]):
     """`jax.make_mesh` with every axis ``Auto``: the shardings this module
-    resolves are placement hints for GSPMD, and the replay's shard_map
-    bodies reduce by hand, so no axis may be ``Explicit`` (the default
-    since JAX 0.8, which rejects e.g. a dot over a sharded contracting
-    dimension instead of inserting the collective)."""
+    resolves are placement hints for GSPMD, so no axis may be ``Explicit``
+    (the default since JAX 0.8, which rejects e.g. a dot over a sharded
+    contracting dimension instead of inserting the collective)."""
     import jax
     from jax.sharding import AxisType
 
@@ -94,9 +93,13 @@ def spec_for_leaf(plan: ShardingPlan, path: str, shape: Tuple[int, ...]) -> P:
     def done(spec_dims) -> P:
         return P(*(prefix + tuple(spec_dims)))
 
-    # embeddings: vocab rows FSDP-sharded on data, d_model contiguous
+    # embeddings: vocab rows FSDP-sharded on data, d_model contiguous; on
+    # a mesh whose data axis does not divide them (a tensor-parallel host),
+    # the rows split over model, a vocabulary slice per device like the
+    # lm_head's columns
     if name == "embed":
-        return done((_fit(plan, "data", dims[0]),) + (None,) * (len(dims) - 1))
+        rows = _fit(plan, "data", dims[0]) or _fit(plan, "model", dims[0])
+        return done((rows,) + (None,) * (len(dims) - 1))
 
     # norms and other vectors: data-FSDP the feature dim when divisible
     if name in ("scale", "bias") or (len(parts) > 1

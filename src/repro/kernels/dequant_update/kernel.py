@@ -64,45 +64,41 @@ def _dq_sub_base_kernel(w_ref, q_ref, b_ref, s_ref, out_ref):
     out_ref[...] = (w_ref[...].astype(jnp.float32) - x).astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "tile"))
+def _grid_call(body, arrays, scalars, block, interpret):
+    """``body`` over 2-D (R, C) `arrays` in (rows, cols) `block` blocks,
+    the edge blocks clipped to the arrays; `scalars` whole in every
+    block."""
+    R, C = arrays[0].shape
+    br, bc = block
+    spec = pl.BlockSpec((br, bc), lambda i, j: (i, j))
+    sspec = pl.BlockSpec(scalars.shape, lambda i, j: (0, 0))
+    return pl.pallas_call(
+        body, grid=(pl.cdiv(R, br), pl.cdiv(C, bc)),
+        in_specs=[spec] * len(arrays) + [sspec], out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((R, C), arrays[0].dtype),
+        interpret=interpret,
+    )(*arrays, scalars)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "block"))
 def dequant_deltagrad_update(w, q, bv, g_changed, scalars, base=None, *,
-                             interpret: bool = False, tile: int = TILE):
-    """All tensors (1, p) with p % tile == 0; scalars (1, 5)."""
-    _, p = w.shape
-    grid = (p // tile,)
-    spec = pl.BlockSpec((1, tile), lambda i: (0, i))
-    sspec = pl.BlockSpec((1, 5), lambda i: (0, 0))
-    out_shape = jax.ShapeDtypeStruct((1, p), w.dtype)
+                             interpret: bool = False,
+                             block=(1, TILE)):
+    """All tensors 2-D (R, C), computed in `block` blocks; scalars
+    (1, 5)."""
     if base is None:
-        return pl.pallas_call(
-            _dq_upd_kernel, grid=grid,
-            in_specs=[spec, spec, spec, spec, sspec],
-            out_specs=spec, out_shape=out_shape, interpret=interpret,
-        )(w, q, bv, g_changed, scalars)
-    return pl.pallas_call(
-        _dq_upd_base_kernel, grid=grid,
-        in_specs=[spec, spec, spec, spec, spec, sspec],
-        out_specs=spec, out_shape=out_shape, interpret=interpret,
-    )(w, q, bv, g_changed, base, scalars)
+        return _grid_call(_dq_upd_kernel, (w, q, bv, g_changed), scalars,
+                          block, interpret)
+    return _grid_call(_dq_upd_base_kernel, (w, q, bv, g_changed, base),
+                      scalars, block, interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "tile"))
+@functools.partial(jax.jit, static_argnames=("interpret", "block"))
 def dequant_sub(w, q, scalars, base=None, *,
-                interpret: bool = False, tile: int = TILE):
-    """(1, p) tensors, p % tile == 0; scalars (1, 1): the dequant scale."""
-    _, p = w.shape
-    grid = (p // tile,)
-    spec = pl.BlockSpec((1, tile), lambda i: (0, i))
-    sspec = pl.BlockSpec((1, 1), lambda i: (0, 0))
-    out_shape = jax.ShapeDtypeStruct((1, p), w.dtype)
+                interpret: bool = False, block=(1, TILE)):
+    """2-D (R, C) tensors, computed in `block` blocks; scalars (1, 1): the
+    dequant scale."""
     if base is None:
-        return pl.pallas_call(
-            _dq_sub_kernel, grid=grid,
-            in_specs=[spec, spec, sspec],
-            out_specs=spec, out_shape=out_shape, interpret=interpret,
-        )(w, q, scalars)
-    return pl.pallas_call(
-        _dq_sub_base_kernel, grid=grid,
-        in_specs=[spec, spec, spec, sspec],
-        out_specs=spec, out_shape=out_shape, interpret=interpret,
-    )(w, q, base, scalars)
+        return _grid_call(_dq_sub_kernel, (w, q), scalars, block, interpret)
+    return _grid_call(_dq_sub_base_kernel, (w, q, base), scalars, block,
+                      interpret)

@@ -43,8 +43,11 @@ SPAN CONTRACT — every span name, where it is emitted, and its args:
                                                 roofline_ratio
     session.plan            core.session        requests  (begin_plan and
                                                 plan_requests of a flush)
-    store.window_stage      core.store          wid  (staging-pool thread)
-    store.prefetch_wait     core.store          wid
+    store.window_stage      core.store          wid  (staging-pool thread;
+                                                also the sharded streamer)
+    store.prefetch_wait     core.store          wid  (a scanned segment or
+                                                an explicit step waiting
+                                                for a prefetched window)
     store.window            core.store          wid, hit
     serve.admit             serve.scheduler     op, tenant, cls
     serve.batch             serve.executor      batch, size, op  (batch is
@@ -85,6 +88,10 @@ publishes it:
     store.windows_fetched        counter    1     core.store
     store.prefetch_hits          counter    1     core.store
     store.host_wait_s            counter    s     core.store
+    store.staged_bytes           counter    B     core.store  (bytes a
+                                                  staged window put on
+                                                  each device: payload,
+                                                  scales, new keyframes)
     queue.admitted               counter    1     serve.queue
     queue.rejected_depth         counter    1     serve.queue
     queue.rejected_tenant        counter    1     serve.queue

@@ -32,16 +32,22 @@ def tree_axpy(s, x, y):
     return jax.tree.map(lambda xi, yi: yi + s * xi, x, y)
 
 
+def _leaf_dot(x, y):
+    """<x, y> contracting every axis in place: no reshape, so a leaf sharded
+    on any of its dims reduces shard-locally and all-reduces one scalar."""
+    axes = tuple(range(x.ndim))
+    return jax.lax.dot_general(
+        x.astype(jnp.float32), y.astype(jnp.float32), ((axes, axes), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
 def tree_vdot(a, b):
     """Full-precision inner product <a, b> over every leaf (HIGHEST: a
     TPU's default f32 dot rounds its inputs to bf16)."""
     leaves_a = jax.tree.leaves(a)
     leaves_b = jax.tree.leaves(b)
-    parts = [
-        jnp.vdot(x.astype(jnp.float32), y.astype(jnp.float32),
-                 precision=jax.lax.Precision.HIGHEST)
-        for x, y in zip(leaves_a, leaves_b)
-    ]
+    parts = [_leaf_dot(x, y) for x, y in zip(leaves_a, leaves_b)]
     return jnp.sum(jnp.stack(parts))
 
 

@@ -163,6 +163,35 @@ def test_dequant_sub_sweep(p, qdtype, delta):
                                atol=2e-2)
 
 
+@pytest.mark.parametrize("shape", [(40, 300), (3, 50, 2100), (8, 64)],
+                         ids=["rows-edge", "rows-cols-edge", "one-block"])
+@pytest.mark.parametrize("qdtype", [jnp.int8, jnp.bfloat16])
+def test_dequant_kernels_keep_array_layout(shape, qdtype):
+    """Arrays of rank 2 and more run in (rows, last dim) blocks, edge
+    blocks clipped, and give the oracles' numbers in their own shape."""
+    rng = np.random.default_rng(len(shape))
+    p = int(np.prod(shape))
+    q, scale, base = _encoded_operand(rng, p, qdtype, True)
+    q, base = q.reshape(shape), base.reshape(shape)
+    w, bv, gc = [jnp.asarray(rng.normal(size=shape).astype(np.float32))
+                 for _ in range(3)]
+    f32 = jnp.float32
+    out = dequant_update(w, q, bv, gc, 0.1, 512.0, 3.0, 1, scale, base,
+                         interpret=True)
+    ref = dequant_update_ref(w, q, bv, gc, f32(0.1), f32(512.0), f32(3.0),
+                             f32(1.0), f32(scale), base)
+    # this scale is not the codec's exact-product one, so the kernel and
+    # the oracle may round q * scale + base differently: one ulp
+    tol = dict(rtol=1e-6, atol=1e-6)
+    assert out.shape == shape
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **tol)
+    out = dequant_sub(w, q, scale, base, interpret=True)
+    assert out.shape == shape
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(dequant_sub_ref(w, q, f32(scale), base)),
+        **tol)
+
+
 def test_dequant_absmax_zero_scale_one():
     """An all-zero residual leaf stores scale 1.0 and q zeros — the kernel
     must return the base exactly (keyframe entries decode bitwise)."""

@@ -83,3 +83,44 @@ def test_flash_forward_compiles_for_v5e(one_chip):
                                     sharding=one_chip)
 
     _assert_kernel_compiles(attention, act(16), act(8), act(8))
+
+
+@pytest.mark.parametrize("shape,spec", [
+    ((92544, 2048), ("model", None)),            # embedding rows
+    ((8, 2048, 8192), (None, None, "model")),    # stacked column-parallel
+], ids=["embed", "w_gate"])
+def test_sharded_dequant_update_compiles_per_device(topo, shape, spec):
+    """On a (data, model) = (1, 4) v5e mesh the replay's fused dequant
+    update runs per device on each one's block of an InternLM2 leaf
+    (`core.store.per_shard`): the Pallas call compiles, and no operand is
+    gathered across the chips."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core.engine import _dequant_fused_update
+    from repro.core.store import EncodedLeaf
+
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    ps = P(*spec)
+
+    def arr(shp, dtype=jnp.float32, s=ps):
+        return jax.ShapeDtypeStruct(shp, dtype,
+                                    sharding=NamedSharding(mesh, s))
+
+    rep = lambda shp, dtype=jnp.float32: arr(shp, dtype, P())
+    leaf = EncodedLeaf(q=arr((2,) + shape, jnp.int8, P(None, *spec)),
+                       scale=rep((2,)),
+                       base=arr((1,) + shape, s=P(None, *spec)),
+                       kidx=rep((2,), jnp.int32))
+
+    def step(p, G, bv, gc, lr, B, dB):
+        return _dequant_fused_update(p, G, 1, bv, gc, lr, B, dB, 1,
+                                     "pallas", shards=(mesh, (ps,)))
+
+    compiled = jax.jit(step).lower(arr(shape), leaf, arr(shape), arr(shape),
+                                   rep(()), rep(()), rep(())).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-gather" not in text
